@@ -131,9 +131,10 @@ class CostModel:
         elif prim in ("reduce_sum", "reduce_max", "reduce_min", "reduce_prod",
                       "argmax", "argmin", "cumsum", "cumlogsumexp", "cummax"):
             flops = float(sum(_numel(a) for a in in_avals))
-        elif prim in ("custom_jvp_call", "custom_vjp_call", "pjit", "closed_call",
-                      "remat", "checkpoint", "scan", "while", "cond"):
-            # estimate nested jaxpr cost
+        elif prim in ("custom_jvp_call", "custom_vjp_call", "jit",
+                      "closed_call", "remat2", "scan", "while", "cond"):
+            # nested jaxpr: the sum of its equations (``jit`` is a nested
+            # jax.jit call, ``remat2`` a jax.checkpoint region)
             flops, extra_b = self._call_cost(eqn)
             bts = max(bts, extra_b)
         else:

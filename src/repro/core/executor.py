@@ -211,6 +211,9 @@ class AsyncSwapExecutor:
     def stop(self):
         self.drain()
         self._stop = True
+        # the worker's last batch holds transfer closures, and through
+        # them the executor's value store: wait until it lets them go
+        self.thread.join()
 
 
 def _is_dropvar(v) -> bool:
@@ -248,10 +251,15 @@ class JaxprExecutor:
         self.device: Dict[str, Any] = {}
         self.host: Dict[str, Any] = {}
         # double-buffered swap-outs: storage -> (completion event,
-        # compressed).  The device copy is retired (trace record, ledger
-        # free, stats) only when the copy has landed — observed at the
-        # next completion-poll point instead of a blocking wait.
-        self._pending_out: Dict[str, Tuple[threading.Event, bool]] = {}
+        # compressed, the storage's write count when the copy began).  The
+        # device copy is retired (trace record, ledger free, stats) only
+        # when the copy has landed — observed at the next completion-poll
+        # point instead of a blocking wait.
+        self._pending_out: Dict[str, Tuple[threading.Event, bool, int]] = {}
+        # per storage, how many values have been written to the device:
+        # an in-place update while a swap-out is in flight shows as a
+        # changed count at retirement
+        self._writes: Dict[str, int] = {}
         # decisions consult THIS iteration's value store, not the (possibly
         # longer-lived, controller-shared) ledger
         self.resident = ResidencyView(self.device)
@@ -355,8 +363,13 @@ class JaxprExecutor:
     def _st(self, name: str) -> str:
         return self.ctx.st(name)
 
-    def _put_device(self, name: str, val: Any) -> None:
+    def _put_device(self, name: str, val: Any, from_host: bool = False) -> None:
         st = self._st(name)
+        if not from_host:
+            # a new value (an input, an op's output, an in-place update):
+            # a host copy holds an older one and may not restore it
+            self._drop_host(st)
+        self._writes[st] = self._writes.get(st, 0) + 1
         if st in self.device:
             self.device[st] = val  # in-place overwrite (aliased update)
             return
@@ -384,6 +397,11 @@ class JaxprExecutor:
         else:
             self.ctx.host_compressed.discard(st)
 
+    def _drop_host(self, st: str) -> None:
+        self.host.pop(st, None)
+        self.ctx.host.discard(st)
+        self.ctx.host_compressed.discard(st)
+
     def _host_fetch(self, st: str):
         """Materialize a device value from the host store (dequantizing a
         compressed copy through the Pallas kernel)."""
@@ -398,9 +416,12 @@ class JaxprExecutor:
         st = self._st(name)
         if st not in self.device or st in self._pending_out:
             return
-        val = self.device[st]
+        # the copy empties the box: a finished swap-out, still referenced
+        # by the swap thread until its next transfer, pins no device memory
+        box = [self.device[st]]
 
         def do():
+            val = box.pop()
             hub = self.telemetry
             ts = hub.now() if hub is not None else 0.0
             t0 = _time.perf_counter()
@@ -415,20 +436,26 @@ class JaxprExecutor:
                     self.ctx.job_id, st, "out", self.ctx.size_of(st),
                     _time.perf_counter() - t0, compressed=compressed, t=ts)
 
+        written = self._writes.get(st, 0)
         if self.async_exec:
             # double-buffered stream: compute proceeds while the copy is
             # on the wire; the device copy is retired at the next poll
             # point, never before the copy lands (paper semantics kept —
             # the ledger free happens only after completion)
             done = self.async_exec.submit("out:" + st, do)
-            self._pending_out[st] = (done, compressed)
+            self._pending_out[st] = (done, compressed, written)
             return
         self.channel.transfer(do)
-        self._retire_out(st, compressed)
+        self._retire_out(st, compressed, written)
 
-    def _retire_out(self, st: str, compressed: bool) -> None:
+    def _retire_out(self, st: str, compressed: bool, written: int) -> None:
         """A swap-out's copy has landed: record, free the device copy,
-        count."""
+        count.  If the storage took a new value while the old one was on
+        the wire (an in-place update), the device keeps the new value and
+        the host copy, now stale, is dropped."""
+        if self._writes.get(st, 0) != written:
+            self._drop_host(st)
+            return
         self.engine.record("swap_out", self.ctx, st)
         self._drop_storage(st)
         self.stats.swap_out_count += 1
@@ -439,12 +466,13 @@ class JaxprExecutor:
         """Non-blocking completion poll of in-flight swap-outs (the other
         half of the double buffer): retire every copy that has landed.
         With ``block=True`` wait for all of them (drain / safe points)."""
-        for st, (done, compressed) in list(self._pending_out.items()):
+        for st, (done, compressed, written) in list(
+                self._pending_out.items()):
             if block:
                 done.wait()
             if done.is_set():
                 del self._pending_out[st]
-                self._retire_out(st, compressed)
+                self._retire_out(st, compressed, written)
 
     def _swap_in(self, name: str, passive: bool) -> bool:
         """Prefetch from host; returns False when there is nothing to fetch
@@ -460,7 +488,7 @@ class JaxprExecutor:
             hub = self.telemetry
             ts = hub.now() if hub is not None else 0.0
             t0 = _time.perf_counter()
-            self._put_device(st, self._host_fetch(st))
+            self._put_device(st, self._host_fetch(st), from_host=True)
             if hub is not None:
                 hub.record_transfer(
                     self.ctx.job_id, st, "in", self.ctx.size_of(st),
@@ -530,6 +558,13 @@ class JaxprExecutor:
 
     # ------------------------------------------------------------------
     def run(self, *args: Any) -> Any:
+        return self.run_flat(jax.tree.leaves(args))
+
+    def run_flat(self, flat: List[Any]) -> Any:
+        """Run one iteration on the flattened inputs.  The executor takes
+        ownership: ``flat`` is emptied once its values are placed, so a
+        caller that keeps no other reference lets a swap-out or release
+        free the device memory the plan says it frees."""
         t_start = _time.perf_counter()
         res_start = 0
         if self.telemetry is not None:
@@ -537,7 +572,6 @@ class JaxprExecutor:
                 self.telemetry.residency.get(self.ctx.job_id, ()))
         # absorb host values preloaded by the controller between iterations
         self.ctx.host |= set(self.host)
-        flat, _ = jax.tree.flatten(args)
         assert len(flat) == len(self.jaxpr.invars), \
             f"expected {len(self.jaxpr.invars)} leaves, got {len(flat)}"
         for v, val in zip(self.jaxpr.invars, flat):
@@ -549,6 +583,8 @@ class JaxprExecutor:
                 self._host_put(st, np.asarray(val), compressed=False)
             else:
                 self._put_device(nm, val)
+        flat.clear()
+        val = None  # nor may the loop variable pin the last input
         for v, val in zip(self.jaxpr.constvars, self.closed.consts):
             self._put_device(self._name_of(v), val)
 
@@ -678,21 +714,15 @@ def _arr_bytes(x) -> int:
 
 
 def _eval_eqn(eqn, invals: List[Any]) -> List[Any]:
-    """Evaluate one jaxpr equation.  Call-like primitives run their
-    sub-jaxpr through jaxpr_as_fun; everything else binds directly."""
+    """Evaluate one jaxpr equation by binding its primitive.  That covers
+    the call-like ones too: ``jit`` (a nested ``jax.jit``) runs its
+    compiled sub-program, ``remat2`` (a ``jax.checkpoint`` region)
+    evaluates its body.  Custom-derivative calls bind rule functions, not
+    their equation params, so their primal body (``call_jaxpr``) runs
+    instead."""
     prim = eqn.primitive
-    name = prim.name
-    if name == "pjit":
-        sub = eqn.params["jaxpr"]
-        outs = jcore.jaxpr_as_fun(sub)(*invals)
-        return list(outs)
-    if name in ("custom_jvp_call", "custom_vjp_call", "custom_vjp_call_jaxpr",
-                "remat", "checkpoint"):
-        sub = eqn.params.get("call_jaxpr") or eqn.params.get("fun_jaxpr") \
-            or eqn.params.get("jaxpr")
-        if sub is not None:
-            closed = sub if hasattr(sub, "consts") else jcore.ClosedJaxpr(sub, [])
-            return list(jcore.jaxpr_as_fun(closed)(*invals))
+    if prim.name in ("custom_jvp_call", "custom_vjp_call"):
+        return list(jcore.jaxpr_as_fun(eqn.params["call_jaxpr"])(*invals))
     outs = prim.bind(*invals, **eqn.params)
     if not prim.multiple_results:
         outs = [outs]

@@ -34,17 +34,14 @@ from .scheduler import MemoryScheduler, SchedulerConfig
 
 @functools.lru_cache(maxsize=4)
 def backend_supports_memory_kinds(platform: Optional[str] = None) -> bool:
-    """Probe: can this backend compile a host-offload annotation under SPMD?"""
-    try:
-        dev = jax.devices(platform)[0] if platform else jax.devices()[0]
-        if dev.platform == "cpu":
-            # the CPU SPMD partitioner rejects annotate_device_placement
-            # (verified empirically; see DESIGN.md §2)
-            return False
-        kinds = getattr(dev, "addressable_memories", lambda: [])()
-        return any(getattr(m, "kind", "") == "pinned_host" for m in kinds)
-    except Exception:
+    """Probe: can this backend compile a host-offload annotation under SPMD?
+    A backend that cannot be asked raises; it is never read as "no"."""
+    dev = jax.devices(platform)[0] if platform else jax.devices()[0]
+    if dev.platform == "cpu":
+        # the CPU SPMD partitioner rejects annotate_device_placement
+        # (verified empirically; see DESIGN.md §2)
         return False
+    return any(m.kind == "pinned_host" for m in dev.addressable_memories())
 
 
 @dataclasses.dataclass(frozen=True)

@@ -75,7 +75,10 @@ class JobHandle:
     job_id: str
     seq: AccessSequence
     closed_jaxpr: Any
-    args: tuple
+    # a train job's (params, opt_state, batch): the initial state until
+    # it runs, None while its executor owns the state, the final state
+    # after a clean finish
+    args: Optional[tuple]
     iterations: int
     priority: float = 1.0
     thread: Optional[threading.Thread] = None
@@ -105,6 +108,9 @@ class JobHandle:
     # admission-time predicted peak (captured only when a DriftMonitor is
     # attached — the measured peak is compared against it on exit)
     predicted_peak: Optional[int] = None
+    # per iteration, the step's outputs after the new params and
+    # optimizer state (a train step's loss)
+    outputs: List[Any] = dataclasses.field(default_factory=list)
 
     @property
     def budget_bytes(self) -> Optional[int]:
@@ -541,10 +547,13 @@ class GlobalController:
                 raise ValueError(f"job {spec.job_id!r} is already live")
             self.scheduler.register_job(seq, priority=spec.priority)
             eff_priority = self.scheduler.priority_of(spec.job_id)
+            # the handle keeps the spec without its payload: the
+            # controller owns the job's arrays from here on
             handle = JobHandle(job_id=spec.job_id, seq=seq,
                                closed_jaxpr=closed, args=captured.args,
                                iterations=spec.iterations,
-                               priority=eff_priority, spec=spec,
+                               priority=eff_priority,
+                               spec=dataclasses.replace(spec, payload=None),
                                fingerprint=captured.fingerprint)
             self.jobs[spec.job_id] = handle
             self.ewma[spec.job_id] = EWMATracker(
@@ -750,8 +759,18 @@ class GlobalController:
 
     # ------------------------------------------------------------------
     def _run_job(self, handle: JobHandle) -> None:
+        import jax
         try:
-            args = handle.args
+            # the executor owns the job's state while it runs: nothing
+            # else may hold the arrays, or a swap-out or release would
+            # free ledger bytes but not device memory
+            params, opt_state, batch = handle.args
+            handle.args = None
+            p_def = jax.tree.structure(params)
+            o_def = jax.tree.structure(opt_state)
+            n_p, n_o = p_def.num_leaves, o_def.num_leaves
+            state = jax.tree.leaves((params, opt_state))
+            del params, opt_state
             version_used = -1
             ex: Optional[JaxprExecutor] = None
             for it in range(handle.iterations):
@@ -789,19 +808,18 @@ class GlobalController:
                     ex.ctx.host_compressed |= compressed
                     handle.executor = ex
                 t0 = _time.perf_counter()
-                outs = ex.run(*args)
+                # run_flat empties the list it is given: after this line no
+                # reference to the iteration's input state is left here
+                state.extend(jax.tree.leaves(batch))
+                outs = ex.run_flat(state)
                 handle.step_times.append(_time.perf_counter() - t0)
                 handle.stats.append(ex.stats)
                 handle.peak_bytes = max(handle.peak_bytes, ex.stats.peak_bytes)
-                # feed params/opt-state back (outputs 0,1 by convention)
-                n_p = len(__import__("jax").tree.flatten(args[0])[0])
-                n_o = len(__import__("jax").tree.flatten(args[1])[0])
-                import jax as _jax
-                p = _jax.tree.unflatten(_jax.tree.structure(args[0]),
-                                        outs[:n_p])
-                o = _jax.tree.unflatten(_jax.tree.structure(args[1]),
-                                        outs[n_p:n_p + n_o])
-                args = (p, o, args[2])
+                # feed params/opt-state back (outputs 0,1 by convention);
+                # the rest (the loss) is what the step reports
+                state = outs[:n_p + n_o]
+                handle.outputs.append(outs[n_p + n_o:])
+                del outs
                 # measured-telemetry feedback (paper step 4): the hub
                 # already holds this iteration's op samples; fold them
                 # into the job's sequence and replan on HUB-reported
@@ -812,6 +830,8 @@ class GlobalController:
                     with self._lock:
                         self._replan()
                 ex.close()
+            handle.args = (jax.tree.unflatten(p_def, state[:n_p]),
+                           jax.tree.unflatten(o_def, state[n_p:]), batch)
         except BaseException as e:  # noqa: BLE001 - surfaced via wait()
             handle.error = e
             handle.error_tb = traceback.format_exc()

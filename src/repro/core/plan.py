@@ -361,19 +361,36 @@ class ChannelReservation:
         return slots[-1][1] - duration if slots else None
 
 
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``.  A device
+# that is not here has no profile: `MachineProfile.for_device` raises.
+DEVICE_PEAKS: Dict[str, Dict[str, object]] = {
+    "TPU v5 lite": {
+        "name": "TPU v5e",
+        "device_memory_bytes": 16 * 2 ** 30,
+        "compute_flops": 197e12,                     # bf16
+        "mem_bw": 819e9,                             # HBM
+        "source": "Google Cloud documentation, 'TPU v5e': 197 TFLOP/s "
+                  "bf16, 16 GiB HBM at 819 GB/s per chip",
+    },
+}
+_TARGET = DEVICE_PEAKS["TPU v5 lite"]
+
+
 @dataclasses.dataclass
 class MachineProfile:
     """Hardware constants used by the planner & simulator.
 
-    Defaults describe the TPU v5e target of this repo; the CPU-container
-    benchmarks calibrate `compute_flops`/`mem_bw` from measurements instead.
+    Defaults describe the TPU v5e target of this repo (its `DEVICE_PEAKS`
+    row), which CPU runs plan against in virtual time; a run on a chip
+    builds its profile with `for_device`.  The CPU-container benchmarks
+    calibrate `compute_flops`/`mem_bw` from measurements instead.
     """
 
-    device_memory_bytes: int = 16 * 2 ** 30          # v5e HBM per chip
+    device_memory_bytes: int = _TARGET["device_memory_bytes"]
     host_link_bw: float = 16e9                       # host<->device DMA (B/s)
     host_link_latency: float = 15e-6                 # per-transfer setup
-    compute_flops: float = 197e12                    # bf16 peak / chip
-    mem_bw: float = 819e9                            # HBM B/s
+    compute_flops: float = _TARGET["compute_flops"]
+    mem_bw: float = _TARGET["mem_bw"]
     ici_bw: float = 50e9                             # per ICI link B/s
     swap_compression: float = 1.0                    # <1.0 with offload_quant
     # int8 quantize-on-offload (kernels/offload_quant): bytes-on-wire ratio
@@ -386,6 +403,22 @@ class MachineProfile:
     # batching n transfers replaces (n-1) host_link_latency setups with
     # (n-1) of these — the term DmaChannel.acquire_batch books against
     dma_batch_overhead: float = 2e-6
+
+    @classmethod
+    def for_device(cls, device) -> "MachineProfile":
+        """The profile of a running device (a ``jax.Device``), from its
+        `DEVICE_PEAKS` row.  A CPU plans against the TPU v5e target in
+        virtual time; an accelerator whose kind has no row is an error."""
+        if device.platform == "cpu":
+            return cls()
+        row = DEVICE_PEAKS.get(device.device_kind)
+        if row is None:
+            raise ValueError(
+                f"no published peaks for device kind {device.device_kind!r} "
+                f"({device.platform}); add a DEVICE_PEAKS row with its "
+                f"source (known: {sorted(DEVICE_PEAKS)})")
+        return cls(device_memory_bytes=row["device_memory_bytes"],
+                   compute_flops=row["compute_flops"], mem_bw=row["mem_bw"])
 
     def swap_time(self, size_bytes: int) -> float:
         eff = size_bytes * self.swap_compression

@@ -13,12 +13,15 @@ contribute nothing (the `pl.when` guard skips their FLOPs on TPU).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .backend import interpret_mode
 
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
@@ -78,7 +81,7 @@ def flash_attention_fwd(q, k, v, *, causal: bool = True,
                         sliding_window: int = 0,
                         block_q: int = DEFAULT_BLOCK_Q,
                         block_k: int = DEFAULT_BLOCK_K,
-                        interpret: bool = True):
+                        interpret: Optional[bool] = None):
     """q: (B, Sq, H, D); k, v: (B, Skv, KV, D) with H % KV == 0.
     Returns (B, Sq, H, D)."""
     b, sq, h, d = q.shape
@@ -125,6 +128,7 @@ def flash_attention_fwd(q, k, v, *, causal: bool = True,
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(q, interpret),
+        name="flash_attention_fwd",
     )(qt, kt, vt)
     return jnp.moveaxis(out[:, :, :sq], 1, 2)

@@ -1,8 +1,9 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On this container everything executes in interpret mode (the kernel body
-runs in Python on CPU — correctness path); on a real TPU `interpret=False`
-compiles to Mosaic.  `on_tpu()` flips automatically.
+Every kernel resolves its ``interpret`` flag through
+``kernels.backend.interpret_mode``: compiled to Mosaic on a TPU, run in
+interpret mode (the kernel body in Python — the correctness path) on the
+CPU test suite.
 """
 from __future__ import annotations
 
@@ -11,16 +12,7 @@ import functools
 import jax
 
 from .flash_attention import flash_attention_fwd
-from .offload_quant import dequantize_blocked, quantize_blocked
 from .ssd_scan import ssd_intra_chunk_fwd
-
-
-@functools.lru_cache(maxsize=1)
-def on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "sliding_window",
@@ -29,18 +21,9 @@ def flash_attention(q, k, v, *, causal: bool = True, sliding_window: int = 0,
                     block_q: int = 128, block_k: int = 128):
     return flash_attention_fwd(q, k, v, causal=causal,
                                sliding_window=sliding_window,
-                               block_q=block_q, block_k=block_k,
-                               interpret=not on_tpu())
+                               block_q=block_q, block_k=block_k)
 
 
 @jax.jit
 def ssd_intra_chunk(xc, dtc, da, bc, cc):
-    return ssd_intra_chunk_fwd(xc, dtc, da, bc, cc, interpret=not on_tpu())
-
-
-def quantize_for_offload(x):
-    return quantize_blocked(x, interpret=not on_tpu())
-
-
-def dequantize_from_offload(q, s, meta):
-    return dequantize_blocked(q, s, meta, interpret=not on_tpu())
+    return ssd_intra_chunk_fwd(xc, dtc, da, bc, cc)

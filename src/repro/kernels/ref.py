@@ -56,17 +56,20 @@ def ssd_intra_chunk_ref(xc, dtc, da, bc, cc):
     return y, states
 
 
-def kv_block_gather_ref(pool, idx):
-    """pool: (N,W); idx: (K,) int -> (K,W) rows of the pool."""
-    return jnp.asarray(pool)[jnp.asarray(idx, jnp.int32)]
+def kv_block_gather_ref(pool, idx, axis: int = 0):
+    """pool: slots on ``axis``; idx: (K,) int -> the K slots of the pool
+    (pool's shape with K on ``axis``)."""
+    return jnp.take(jnp.asarray(pool), jnp.asarray(idx, jnp.int32), axis=axis)
 
 
-def kv_block_scatter_ref(pool, idx, blocks):
-    """pool: (N,W); idx: (K,) int; blocks: (K,W) -> pool with ``idx`` rows
-    replaced by ``blocks``; all other rows untouched."""
-    pool = jnp.asarray(pool)
-    return pool.at[jnp.asarray(idx, jnp.int32)].set(
-        jnp.asarray(blocks, pool.dtype))
+def kv_block_scatter_ref(pool, idx, blocks, axis: int = 0):
+    """pool: slots on ``axis``; idx: (K,) int; blocks: K slots on ``axis``
+    -> pool with the ``idx`` slots replaced by ``blocks``; all other slots
+    untouched."""
+    pool = jnp.moveaxis(jnp.asarray(pool), axis, 0)
+    blocks = jnp.moveaxis(jnp.asarray(blocks, pool.dtype), axis, 0)
+    out = pool.at[jnp.asarray(idx, jnp.int32)].set(blocks)
+    return jnp.moveaxis(out, 0, axis)
 
 
 def quantize_blocked_ref(x, block: int = 512):

@@ -13,10 +13,13 @@ a 256×256 fp32 tile).  The cheap inter-chunk recurrence stays in jnp
 """
 from __future__ import annotations
 
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from .backend import interpret_mode
 
 
 def _ssd_kernel(x_ref, dt_ref, da_ref, b_ref, c_ref, y_ref, st_ref):
@@ -44,7 +47,8 @@ def _ssd_kernel(x_ref, dt_ref, da_ref, b_ref, c_ref, y_ref, st_ref):
     st_ref[0, 0, 0] = st.astype(st_ref.dtype)
 
 
-def ssd_intra_chunk_fwd(xc, dtc, da, bc, cc, *, interpret: bool = True):
+def ssd_intra_chunk_fwd(xc, dtc, da, bc, cc, *,
+                        interpret: Optional[bool] = None):
     """xc: (B,NC,Q,H,P); dtc/da: (B,NC,Q,H); bc/cc: (B,NC,Q,N).
 
     Returns y_diag: (B,NC,Q,H,P), states: (B,NC,H,P,N) — matching the jnp
@@ -75,7 +79,7 @@ def ssd_intra_chunk_fwd(xc, dtc, da, bc, cc, *, interpret: bool = True):
             jax.ShapeDtypeStruct((b, nc, q, h, p), jnp.float32),
             jax.ShapeDtypeStruct((b, nc, h, n, p), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(xc, interpret),
     )(xc, dtc, da, bc, cc)
     # states stored (N,P) per head -> transpose to (P,N)
     return y, jnp.swapaxes(st, -1, -2)

@@ -19,6 +19,7 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.data.pipeline import DataConfig, Prefetcher, TokenStream
+from repro.launch.compile_cache import setup_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.launch.sharding import MeshRules, use_rules
 from repro.launch.steps import (TrainStepConfig, build_train_step,
@@ -47,6 +48,7 @@ def main(argv=None) -> int:
     ap.add_argument("--d-model", type=int, default=0)
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args(argv)
+    setup_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

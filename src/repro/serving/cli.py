@@ -11,8 +11,11 @@ from __future__ import annotations
 
 import argparse
 
+import jax
+
 from ..core.engine import MemoryEngine
 from ..core.plan import MachineProfile
+from ..launch.compile_cache import setup_compile_cache
 from .engine import ServingEngine
 from .traces import TRACE_NAMES, make_trace
 
@@ -21,6 +24,8 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description="continuous-batching LM serving")
     ap.add_argument("--arch", default="tinyllama-1.1b")
     ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--full", dest="reduced", action="store_false",
+                    help="serve the architecture at its published widths")
     ap.add_argument("--max-sequences", type=int, default=4,
                     help="batch slots in the shared decode cache")
     ap.add_argument("--prompt-len", type=int, default=8)
@@ -34,6 +39,7 @@ def main(argv=None) -> int:
                     help="disable KV residency scheduling (baseline)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    setup_compile_cache()
 
     eng = ServingEngine(args.arch, reduced=args.reduced,
                         max_sequences=args.max_sequences,
@@ -41,8 +47,8 @@ def main(argv=None) -> int:
     requests = make_trace(args.trace, args.requests, seed=args.seed,
                           prompt_len=args.prompt_len, gen_len=args.gen)
     budget = args.budget_kb * 1024 or None
-    mem = MemoryEngine(profile=MachineProfile(), capacity_bytes=budget,
-                       trace=True)
+    mem = MemoryEngine(profile=MachineProfile.for_device(jax.devices()[0]),
+                       capacity_bytes=budget, trace=True)
     report, outputs = eng.serve(requests, budget_bytes=budget,
                                 schedule=not args.no_schedule,
                                 block_tokens=args.block_tokens, engine=mem)

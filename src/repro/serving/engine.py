@@ -97,6 +97,7 @@ class PrefillResult:
     prompt_len: int
     first_token: int
     cache: object            # batch-1 cache pytree, positions [0, prompt_len)
+    logits: object = None    # the last prompt position's logits, (vocab,)
 
 
 class ServingEngine:
@@ -117,19 +118,11 @@ class ServingEngine:
         self.api = get_model(cfg)
         self.max_sequences = int(max_sequences)
         self.max_len = int(max_len)
-        try:
-            self.rules: Optional[MeshRules] = MeshRules(make_host_mesh(),
-                                                        cfg=cfg)
-        except Exception:  # mesh API unavailable: run unsharded
-            self.rules = None
+        self.rules = MeshRules(make_host_mesh(), cfg=cfg)
         self.params, _ = self.api.init(jax.random.PRNGKey(seed))
         self.cache, _ = self.api.init_cache(self.max_sequences, self.max_len)
-        serve_step = build_serve_step(self.api, self.rules)
-        if self.rules is not None:
-            with use_rules(self.rules):
-                self._step = jax.jit(serve_step)
-        else:
-            self._step = jax.jit(serve_step)
+        with use_rules(self.rules):
+            self._step = jax.jit(build_serve_step(self.api, self.rules))
         self._axes = _cache_leaf_axes(self.api, self.max_sequences,
                                       self.max_len)
         # per-token-per-sequence cache bytes, from abstract shapes only
@@ -217,10 +210,8 @@ class ServingEngine:
         for i, (leaf, spec) in enumerate(zip(leaves, self._axes)):
             if spec.batch is None:
                 continue
-            moved = jnp.moveaxis(leaf, spec.batch, 0)
-            pool = moved.reshape(moved.shape[0], -1)
-            rows = kv_block_gather(pool, slots).reshape(
-                (len(todo),) + moved.shape[1:])
+            rows = jnp.moveaxis(
+                kv_block_gather(leaf, slots, axis=spec.batch), spec.batch, 0)
             red = self._reduced_axis(spec)
             for k, s in enumerate(todo):
                 row = rows[k]
@@ -253,10 +244,11 @@ class ServingEngine:
         for i, spec in enumerate(self._axes):
             if spec.batch is None:
                 continue
-            moved = jnp.moveaxis(leaves[i], spec.batch, 0)
-            pool = moved.reshape(moved.shape[0], -1)
-            rows = kv_block_gather(pool, slots).reshape(
-                (len(todo),) + moved.shape[1:])
+            # the cohort's rows, slot-major (K slots, then the leaf's
+            # other axes): a copy of K rows, never of the whole leaf
+            rows = jnp.moveaxis(
+                kv_block_gather(leaves[i], slots, axis=spec.batch),
+                spec.batch, 0)
             red = self._reduced_axis(spec)
             for k, s in enumerate(todo):
                 arr = self._shadow[s.rid].get(i)
@@ -270,10 +262,9 @@ class ServingEngine:
                     sl[0] = k
                     sl[red + 1] = slice(0, s.pos)
                     rows = rows.at[tuple(sl)].set(jnp.asarray(arr))
-            newpool = kv_block_scatter(pool, slots,
-                                       rows.reshape(len(todo), -1))
-            leaves[i] = jnp.moveaxis(newpool.reshape(moved.shape), 0,
-                                     spec.batch)
+            leaves[i] = kv_block_scatter(
+                leaves[i], slots, jnp.moveaxis(rows, 0, spec.batch),
+                axis=spec.batch)
         for s in todo:
             self._shadow.pop(s.rid, None)
         self.cache = jax.tree_util.tree_unflatten(treedef, leaves)
@@ -298,7 +289,8 @@ class ServingEngine:
                                        jnp.int32(i))
         first = int(jnp.argmax(logits[0, -1]))
         return PrefillResult(rid=rid, prompt=prompt, prompt_len=len(prompt),
-                             first_token=first, cache=cache)
+                             first_token=first, cache=cache,
+                             logits=logits[0, -1])
 
     def insert(self, pr: PrefillResult, slot: int,
                state: Optional[SeqState] = None) -> None:
@@ -414,8 +406,9 @@ class ServingEngine:
         residency decision against the shared ledger; this engine's hooks
         execute them on the jitted model.  Returns the session report and
         the per-request generated token ids."""
-        mem = engine or MemoryEngine(profile=MachineProfile(),
-                                     capacity_bytes=None, trace=True)
+        mem = engine or MemoryEngine(
+            profile=MachineProfile.for_device(jax.devices()[0]),
+            capacity_bytes=None, trace=True)
         self._states.clear()
         self._outputs.clear()
         self._shadow.clear()

@@ -242,7 +242,7 @@ def test_job_thread_failure_surfaces_loudly(monkeypatch):
     def boom(self, *args, **kwargs):
         raise RuntimeError("executor exploded")
 
-    monkeypatch.setattr(JaxprExecutor, "run", boom)
+    monkeypatch.setattr(JaxprExecutor, "run_flat", boom)
     gc = GlobalController(profile=PROFILE, async_swap=False)
     p, o, b = _make_job(0)
     gc.submit(JobSpec("doomed", iterations=1,

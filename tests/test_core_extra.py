@@ -172,3 +172,81 @@ def test_make_remat_policy_cpu_fallback():
                            save_names=frozenset({"keep"}))
     pol = make_remat_policy(dec, offload=True)  # CPU: falls back
     assert callable(pol)
+
+
+def _nested_call_steps():
+    def body(w, x):
+        return jnp.tanh(x @ w) @ w.T
+
+    def plain(w, x):
+        return body(w, x)
+
+    def jitted(w, x):
+        return jax.jit(body)(w, x)
+
+    def remat(w, x):
+        return jax.checkpoint(body)(w, x)
+
+    return plain, jitted, remat
+
+
+def test_cost_model_prices_nested_calls_like_the_plain_step():
+    """A step wrapped in jax.jit (the ``jit`` primitive) or jax.checkpoint
+    (``remat2``) costs the same FLOPs as the plain one, not one
+    elementwise op."""
+    w = jnp.ones((64, 64), jnp.float32)
+    x = jnp.ones((8, 64), jnp.float32)
+    cm = CostModel()
+
+    def flops(fn):
+        closed = jax.make_jaxpr(fn)(w, x)
+        return sum(cm.eqn_cost(e)[0] for e in closed.jaxpr.eqns)
+
+    plain, jitted, remat = _nested_call_steps()
+    prims = {e.primitive.name
+             for f in (jitted, remat)
+             for e in jax.make_jaxpr(f)(w, x).jaxpr.eqns}
+    assert {"jit", "remat2"} <= prims
+    assert flops(plain) > 2 * 8 * 64 * 64
+    assert flops(jitted) == flops(plain)
+    assert flops(remat) == flops(plain)
+
+
+def test_executor_runs_nested_calls():
+    """The interpreting executor evaluates nested jit / checkpoint /
+    custom-derivative equations to the reference outputs."""
+    from repro.core.executor import JaxprExecutor, reference_outputs
+    from repro.core.graph_capture import capture
+
+    w = jax.random.normal(jax.random.PRNGKey(1), (16, 16))
+    x = jax.random.normal(jax.random.PRNGKey(2), (4, 16))
+    plain, jitted, remat = _nested_call_steps()
+
+    def step(w, x):
+        return jitted(w, x) + remat(w, x) + jax.nn.relu(plain(w, x))
+
+    seq, closed = capture(step, w, x)
+    got = JaxprExecutor(closed, seq).run(w, x)
+    want = reference_outputs(closed, w, x)
+    for g, r in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(r))
+
+
+def test_machine_profile_for_device_reads_the_peaks_table():
+    """A chip's profile comes from its DEVICE_PEAKS row; a CPU plans
+    against the v5e target; an accelerator with no row is an error."""
+    from types import SimpleNamespace
+
+    import pytest
+
+    from repro.core.plan import DEVICE_PEAKS
+
+    v5e = MachineProfile.for_device(
+        SimpleNamespace(platform="tpu", device_kind="TPU v5 lite"))
+    assert v5e.compute_flops == 197e12 and v5e.mem_bw == 819e9
+    assert v5e.device_memory_bytes == 16 * 2 ** 30
+    assert "Google Cloud" in DEVICE_PEAKS["TPU v5 lite"]["source"]
+    assert MachineProfile.for_device(jax.devices("cpu")[0]) == MachineProfile()
+    with pytest.raises(ValueError, match="TPU v99"):
+        MachineProfile.for_device(
+            SimpleNamespace(platform="tpu", device_kind="TPU v99"))

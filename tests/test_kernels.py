@@ -10,8 +10,8 @@ from conftest import hypothesis_or_stub
 given, settings, st = hypothesis_or_stub()
 
 from repro.kernels import ref
-from repro.kernels.ops import (dequantize_from_offload, flash_attention,
-                               quantize_for_offload, ssd_intra_chunk)
+from repro.kernels.offload_quant import dequantize_blocked, quantize_blocked
+from repro.kernels.ops import flash_attention, ssd_intra_chunk
 
 KEY = jax.random.PRNGKey(0)
 
@@ -73,8 +73,8 @@ def test_ssd_intra_chunk_sweep(b, nc, q, h, p, n):
        scale=st.floats(1e-3, 1e3), seed=st.integers(0, 999))
 def test_quant_roundtrip_property(rows, cols, scale, seed):
     x = jax.random.normal(jax.random.PRNGKey(seed), (rows, cols)) * scale
-    q, s, meta = quantize_for_offload(x)
-    xr = dequantize_from_offload(q, s, meta)
+    q, s, meta = quantize_blocked(x)
+    xr = dequantize_blocked(q, s, meta)
     assert xr.shape == x.shape
     # per-block error bound: absmax/127 per element
     err = np.abs(np.asarray(xr) - np.asarray(x))
@@ -84,13 +84,13 @@ def test_quant_roundtrip_property(rows, cols, scale, seed):
 
 def test_quant_matches_numpy_ref():
     x = jax.random.normal(KEY, (37, 129)) * 3
-    q, s, meta = quantize_for_offload(x)
+    q, s, meta = quantize_blocked(x)
     q2, s2, meta2 = ref.quantize_blocked_ref(np.asarray(x))
     np.testing.assert_array_equal(np.asarray(q).reshape(-1),
                                   q2.reshape(-1))
     xr = ref.dequantize_blocked_ref(np.asarray(q), np.asarray(s), meta2)
     np.testing.assert_allclose(
-        xr, np.asarray(dequantize_from_offload(q, s, meta)), rtol=1e-6)
+        xr, np.asarray(dequantize_blocked(q, s, meta)), rtol=1e-6)
 
 
 def test_flash_inside_model_forward():
@@ -113,3 +113,38 @@ def test_flash_inside_model_forward():
     y1 = attention_block(b.params, x, pos, cfg=cfg2)
     np.testing.assert_allclose(np.asarray(y0), np.asarray(y1),
                                rtol=5e-4, atol=5e-4)
+
+
+@pytest.mark.parametrize("shape,dtype", [
+    ((600, 512), jnp.float32),       # several row tiles, a ragged last one
+    ((256, 1024), jnp.bfloat16),     # exactly two row tiles
+    ((3, 5), jnp.float32),           # fewer rows than one sublane tile
+])
+def test_quant_row_tiles_match_numpy_ref(shape, dtype):
+    x = (jax.random.normal(KEY, shape) * 5).astype(dtype)
+    q, s, meta = quantize_blocked(x)
+    q2, s2, meta2 = ref.quantize_blocked_ref(np.asarray(x))
+    assert q.shape == q2.shape and s.shape == s2.shape
+    # bf16 source: a value on a rounding tie may land one step apart
+    step = np.abs(np.asarray(q, np.int32) - q2.astype(np.int32)).max()
+    assert step <= (0 if dtype == jnp.float32 else 1)
+    np.testing.assert_allclose(np.asarray(s), s2, rtol=1e-6)
+    xr = dequantize_blocked(q, s, meta)
+    assert xr.shape == x.shape and xr.dtype == x.dtype
+    np.testing.assert_allclose(
+        np.asarray(xr, np.float32),
+        ref.dequantize_blocked_ref(np.asarray(q), np.asarray(s), meta2)
+        .astype(np.float32), rtol=1e-2, atol=1e-2)
+
+
+def test_interpret_switch_follows_the_platform():
+    """One switch decides interpret mode: compiled on a TPU, interpreted
+    elsewhere; an explicit flag (the compile-only tests) wins."""
+    from repro.kernels.backend import interpret_mode, platform_of
+
+    x = jnp.ones((8, 128))
+    assert platform_of(x) == jax.default_backend()
+    assert interpret_mode(x) is (platform_of(x) != "tpu")
+    assert interpret_mode(np.ones(3)) is (jax.default_backend() != "tpu")
+    assert interpret_mode(x, False) is False
+    assert interpret_mode(x, True) is True

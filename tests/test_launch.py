@@ -134,3 +134,28 @@ def test_a2a_moe_single_device_fallback():
     batch = api.input_specs(ShapeSpec("s", 32, 2, "train"), abstract=False)
     loss = api.loss(params, batch)
     assert np.isfinite(float(loss))
+
+
+# ----------------------------------------------------------- compile cache
+@pytest.mark.parametrize("placed", [None, "/elsewhere/jax-cache"])
+def test_compile_cache_dir_is_fixed_or_placed(monkeypatch, placed):
+    """The persistent compile cache is where JAX_COMPILATION_CACHE_DIR
+    says (left to JAX), else at one fixed directory in the checkout."""
+    from repro.launch import compile_cache as cc
+
+    before = jax.config.jax_compilation_cache_dir
+    if placed:
+        monkeypatch.setenv(cc.ENV, placed)
+    else:
+        monkeypatch.delenv(cc.ENV, raising=False)
+    try:
+        used = cc.setup_compile_cache()
+        after = jax.config.jax_compilation_cache_dir
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    if placed:
+        assert used == placed and after == before
+    else:
+        assert used == after == str(cc.DEFAULT_DIR)
+        assert cc.DEFAULT_DIR.name == ".jax_cache"
+        assert (cc.DEFAULT_DIR.parent / "pyproject.toml").is_file()

@@ -161,6 +161,25 @@ def test_direction_change_defers_to_the_next_launch():
         ex.stop()
 
 
+def test_stop_lets_go_of_the_last_transfer():
+    """After stop() no transfer closure is left on the worker: what it
+    captured (an executor's value store, and through it device arrays)
+    is freed as soon as its owner lets go of it."""
+    import weakref
+
+    class Store:
+        pass
+
+    ex = AsyncSwapExecutor(DmaChannel())
+    store = Store()
+    ref = weakref.ref(store)
+    ex.submit("out:x", lambda s=store: s)
+    del store
+    ex.stop()
+    assert not ex.thread.is_alive()
+    assert ref() is None
+
+
 # ----------------------------------------------------------------------
 # TelemetryHub per-thread buffering
 # ----------------------------------------------------------------------
@@ -250,3 +269,44 @@ def test_kv_gather_scatter_roundtrip_is_identity(kv_pool):
     rows = kv_block_gather(pool, idx)
     back = np.asarray(kv_block_scatter(pool, idx, rows))
     np.testing.assert_array_equal(back, pool)
+
+
+# ----------------------------------------------------------------------
+# The (pre, slots, rows, lanes) block layout: the slot axis in place, as
+# the serving engine passes a cache leaf, and rows tiled past one block
+# ----------------------------------------------------------------------
+@pytest.mark.parametrize("shape,axis", [
+    ((3, 6, 40, 4, 64), 1),     # cache leaf: (layers, slots, len, kv, hd)
+    ((2, 5, 7, 3), 1),          # a slot row that is not a lane multiple
+    ((6, 4, 4096, 128), 0),     # 2 MiB slot rows: several row tiles
+    ((2, 3, 4, 5), 3),          # slots on the last axis
+])
+def test_kv_block_copy_slot_axis_matches_ref(shape, axis):
+    from repro.kernels.kv_block_copy import kv_block_gather, kv_block_scatter
+    from repro.kernels.ref import kv_block_gather_ref, kv_block_scatter_ref
+
+    rng = np.random.default_rng(11)
+    pool = rng.standard_normal(shape).astype(np.float32)
+    n = shape[axis]
+    idx = np.asarray(rng.permutation(n)[:min(3, n)], np.int32)
+    got = np.asarray(kv_block_gather(pool, idx, axis=axis))
+    np.testing.assert_array_equal(
+        got, np.asarray(kv_block_gather_ref(pool, idx, axis)))
+    blocks = rng.standard_normal(got.shape).astype(np.float32)
+    out = np.asarray(kv_block_scatter(pool, idx, blocks, axis=axis))
+    np.testing.assert_array_equal(
+        out, np.asarray(kv_block_scatter_ref(pool, idx, blocks, axis)))
+    untouched = np.setdiff1d(np.arange(n), idx)
+    np.testing.assert_array_equal(np.take(out, untouched, axis=axis),
+                                  np.take(pool, untouched, axis=axis))
+
+
+def test_kv_block_view_tiles_fit_fast_memory():
+    """A full-width TinyLlama cache leaf (a 23 MB slot row) is cut into
+    (8, 128)-aligned row tiles of at most BLOCK_BYTES each."""
+    from repro.kernels.kv_block_copy import BLOCK_BYTES, LANES, _view
+
+    view, tile = _view((22, 8, 2048, 4, 64), 1, 2)
+    assert view == (22, 8, 2048 * 4 * 64 // LANES, LANES)
+    assert tile % 8 == 0 and view[2] % tile == 0
+    assert tile * LANES * 2 <= BLOCK_BYTES

@@ -106,3 +106,90 @@ def test_global_controller_multi_job():
     assert all(h.done and h.error is None for h in gc.jobs.values())
     assert gc.global_peak_bytes > 0
     assert gc.replan_count >= 1
+
+
+def test_controller_job_frees_its_input_state_during_a_step(monkeypatch):
+    """The controller hands a job's state to the executor and keeps no
+    reference of its own, so the live bytes of an unscheduled step never
+    exceed the step's free-at-last-use peak: the old params and moments
+    are freed as the update replaces them."""
+    import gc as pygc
+
+    import repro.core.executor as executor_mod
+    from helpers import mlp_params
+    from repro.core.peak_analysis import analyze
+    from repro.optim.adam import adamw_init
+
+    def live_bytes():
+        return sum(a.nbytes for a in jax.live_arrays())
+
+    peaks = []
+    bind = executor_mod._eval_eqn
+
+    def eval_and_measure(eqn, invals):
+        outs = bind(eqn, invals)
+        peaks.append(live_bytes())
+        return outs
+
+    monkeypatch.setattr(executor_mod, "_eval_eqn", eval_and_measure)
+    pygc.collect()
+    baseline = live_bytes()
+
+    def payload():
+        p = mlp_params(jax.random.PRNGKey(0), [32, 256, 256, 4])
+        b = (jax.random.normal(jax.random.PRNGKey(1), (8, 32)),
+             jax.random.normal(jax.random.PRNGKey(2), (8, 4)))
+        return mlp_train_step, p, adamw_init(p), b
+
+    ctl = GlobalController(profile=PROFILE, pipeline_name="vanilla")
+    handle = ctl.submit(JobSpec("own", iterations=3, payload=payload()))
+    ctl.wait(timeout=180)
+    assert handle.error is None
+    step_peak = analyze([handle.seq]).peak_bytes
+    state = sum(a.nbytes for a in jax.tree.leaves(handle.args[:2]))
+    assert state > 0.1 * step_peak  # pinning the old state would show
+    assert max(peaks) - baseline <= step_peak * 1.01
+
+
+def test_scheduled_losses_match_vanilla_with_slow_swap_outs(monkeypatch):
+    """Swap-outs still on the wire when the optimizer updates their
+    storage in place (a slow host link) must not cost the new value: the
+    scheduled run's losses stay bit-identical to the unscheduled run's."""
+    import time
+
+    from helpers import mlp_params
+    from repro.core.executor import AsyncSwapExecutor
+    from repro.core.peak_analysis import analyze
+    from repro.optim.adam import adamw_init
+
+    submit = AsyncSwapExecutor.submit
+
+    def slow_out(self, key, fn):
+        if key.startswith("out:"):
+            def late(fn=fn):
+                time.sleep(0.01)
+                fn()
+            return submit(self, key, late)
+        return submit(self, key, fn)
+
+    monkeypatch.setattr(AsyncSwapExecutor, "submit", slow_out)
+
+    def payload():
+        p = mlp_params(jax.random.PRNGKey(0), [32, 128, 128, 128, 4])
+        b = (jax.random.normal(jax.random.PRNGKey(1), (8, 32)),
+             jax.random.normal(jax.random.PRNGKey(2), (8, 4)))
+        return mlp_train_step, p, adamw_init(p), b
+
+    def run(pipeline, budget=None):
+        ctl = GlobalController(
+            profile=PROFILE, pipeline_name=pipeline,
+            scheduler_config=SchedulerConfig(memory_budget_bytes=budget))
+        h = ctl.submit(JobSpec(pipeline, iterations=3, payload=payload()))
+        ctl.wait(timeout=180)
+        assert h.error is None
+        return [np.asarray(out[0]) for out in h.outputs], h
+
+    ref, h = run("vanilla")
+    got, h2 = run("tensile", int(0.6 * analyze([h.seq]).peak_bytes))
+    assert sum(s.swap_out_count for s in h2.stats) > 0
+    assert [x.tobytes() for x in got] == [x.tobytes() for x in ref]

@@ -49,6 +49,8 @@ def _fmt_bytes(n: float) -> str:
 
 
 def cmd_start(args: argparse.Namespace) -> int:
+    from repro.launch.compile_cache import setup_compile_cache
+    setup_compile_cache()
     daemon = SchedulerDaemon(args.root,
                              capacity_bytes=args.capacity_bytes,
                              poll_interval=args.poll_interval)
@@ -183,8 +185,7 @@ def cmd_smoke(args: argparse.Namespace) -> int:
     print("phase A: daemon subprocess, 3 wire jobs, drain")
     proc = subprocess.Popen(
         [sys.executable, os.path.abspath(__file__), "start", "--root", root,
-         "--poll-interval", "0.02"],
-        env={**os.environ, "JAX_PLATFORMS": "cpu"})
+         "--poll-interval", "0.02"])
     client = ServiceClient(root)
     try:
         deadline = time.time() + 120
