@@ -44,6 +44,7 @@ from typing import Callable, Dict, List, Optional, Set, Tuple
 
 import numpy as np
 
+from ..obs.spans import span
 from .access import AccessSequence, TensorKind
 from .peak_analysis import PERSISTENT_KINDS, storage_of
 from .plan import (EventType, MachineProfile, ScheduleEvent,
@@ -344,7 +345,7 @@ class DmaChannel:
         return False
 
     def transfer(self, fn: Callable):
-        with self.lock:
+        with span("tensile.transfer", members=1), self.lock:
             t0 = _time.perf_counter()
             out = fn()
             self.busy_s += _time.perf_counter() - t0
@@ -354,7 +355,8 @@ class DmaChannel:
         """Run several copies under ONE channel hold — the real-time form
         of a coalesced batch: a single acquisition of the wire covers the
         whole cohort instead of one lock round-trip per member."""
-        with self.lock:
+        fns = list(fns)
+        with span("tensile.transfer", members=len(fns)), self.lock:
             t0 = _time.perf_counter()
             out = [fn() for fn in fns]
             self.busy_s += _time.perf_counter() - t0

@@ -38,6 +38,7 @@ import jax
 import numpy as np
 from jax.extend import core as jcore
 
+from ..obs.spans import span
 from .access import AccessSequence
 from .engine import (INPUT_AWAIT_PREFETCH, INPUT_PASSIVE_SWAP_IN,
                      INPUT_RESIDENT, DeviceLedger, DmaChannel, MemoryEngine,
@@ -53,15 +54,29 @@ SwapChannel = DmaChannel
 
 @dataclasses.dataclass
 class ExecutionStats:
+    """One iteration's counters.  Its wall time splits into disjoint parts
+    on the executor's thread: ``dispatch_s`` (binding equations, those of
+    recomputes too), ``sync_s`` (waiting for each equation's results, done
+    when a telemetry hub is attached), ``transfer_s`` (inside the DMA
+    channel, where ``stall_time_s`` does not already count it),
+    ``stall_time_s`` (waiting for operands not on the device) and
+    ``self_s``, the rest: the executor's own bookkeeping.  The controller
+    fills the last three fields before the iteration starts."""
     peak_bytes: int = 0
     wall_time_s: float = 0.0
     swap_out_count: int = 0
     swap_in_count: int = 0
+    # storage bytes of the swaps counted above (uncompressed sizes)
+    swap_out_bytes: int = 0
+    swap_in_bytes: int = 0
     passive_swap_ins: int = 0
     recompute_count: int = 0
     compressed_swaps: int = 0
-    op_latencies: Optional[List[float]] = None
     stall_time_s: float = 0.0
+    dispatch_s: float = 0.0
+    sync_s: float = 0.0
+    transfer_s: float = 0.0
+    self_s: float = 0.0
     # mid-iteration plan hot-swaps applied at a safe point
     hot_swaps: int = 0
     # queued (unstarted) prefetches cancelled when a hot-swap revised
@@ -70,6 +85,11 @@ class ExecutionStats:
     # measured per-job residency timeline of THIS iteration, (t, bytes)
     # in hub time — filled from the TelemetryHub when one is attached
     residency_timeline: Optional[List[tuple]] = None
+    # the controller's time from the previous iteration's end to this
+    # one's start, the part of it spent replanning, and the replans
+    before_s: float = 0.0
+    replan_s: float = 0.0
+    replans: int = 0
 
 
 class AsyncSwapExecutor:
@@ -226,10 +246,10 @@ class JaxprExecutor:
                  accountant: Optional[DeviceLedger] = None,
                  channel: Optional[DmaChannel] = None,
                  async_swap: bool = False,
-                 measure_latency: bool = False,
                  host_resident_inputs: Optional[Set[str]] = None,
                  engine: Optional[MemoryEngine] = None,
-                 telemetry: Optional[TelemetryHub] = None):
+                 telemetry: Optional[TelemetryHub] = None,
+                 iteration: int = 0, plan_version: int = 0):
         self.closed = closed_jaxpr
         self.jaxpr = closed_jaxpr.jaxpr
         self.seq = seq
@@ -243,7 +263,9 @@ class JaxprExecutor:
         self.accountant = self.engine.ledger
         self.channel = self.engine.channel
         self.async_exec = AsyncSwapExecutor(self.channel) if async_swap else None
-        self.measure_latency = measure_latency
+        # the job's iteration and plan version, for the iteration's span
+        self.iteration = iteration
+        self.plan_version = plan_version
         # storages whose *input* value starts on host (previous iteration's
         # cross-iteration swap-out; paper Fig. 1(c) steady state)
         self.host_resident_inputs: Set[str] = set(host_resident_inputs or ())
@@ -277,7 +299,7 @@ class JaxprExecutor:
         for i, eqn in enumerate(self.jaxpr.eqns):
             for v in eqn.outvars:
                 self.producer[self._name_of(v)] = i
-        self.stats = ExecutionStats(op_latencies=[] if measure_latency else None)
+        self.stats = ExecutionStats()
         self._cur_idx = -1
         # pending mid-iteration plan hot-swap: (plan, eligible safe ops),
         # set by the controller thread, consumed at a safe point in run()
@@ -322,35 +344,39 @@ class JaxprExecutor:
             plan, safe_ops = self._pending_plan
             if idx not in safe_ops:
                 return
-            # a splice needs quiescence: wait out our own in-flight
-            # swap-outs (short copies; the pre-double-buffer executor
-            # blocked on them at issue time, so this preserves the PR-4
-            # cancel/defer semantics exactly)
-            self._poll_swap_outs(block=True)
-            if self.async_exec and self.async_exec.inflight:
-                cancelled = self.async_exec.cancel_unstarted("in:")
-                if cancelled is None:
-                    # a prefetch is physically on the wire: defer to the
-                    # next safe point.  cancel_unstarted cancels NOTHING
-                    # in that case, so the still-running old plan keeps
-                    # every prefetch it queued.
-                    return
-                with self.async_exec.state_lock:
-                    blocking = [k for k in self.async_exec.inflight
-                                if k not in self.async_exec.poisoned]
-                if blocking:
-                    return       # e.g. a swap-out raced in: next point
-                self.stats.canceled_swap_ins += len(cancelled)
-            self.plan = plan
-            self.ctx.set_plan(plan)
-            self.stats.hot_swaps += 1
-            self._pending_plan = None
-            rec = self.engine.recorder
-            if rec is not None:
-                t = self.telemetry.now() if self.telemetry is not None \
-                    else 0.0
-                rec.instant("hot_swap", t, job_id=self.ctx.job_id,
-                            site="safe-point", op_idx=idx)
+            with span("tensile.hot_swap", job=self.ctx.job_id, eqn=idx):
+                self._splice(plan, idx)
+
+    def _splice(self, plan: SchedulingPlan, idx: int) -> None:
+        """Apply the pending plan at safe point ``idx``, or leave it
+        pending for the next one; called under ``_plan_lock``."""
+        # a splice needs quiescence: wait out our own in-flight swap-outs
+        # (short copies; the pre-double-buffer executor blocked on them at
+        # issue time, so this keeps its cancel/defer semantics exactly)
+        self._poll_swap_outs(block=True)
+        if self.async_exec and self.async_exec.inflight:
+            cancelled = self.async_exec.cancel_unstarted("in:")
+            if cancelled is None:
+                # a prefetch is physically on the wire: defer to the next
+                # safe point.  cancel_unstarted cancels NOTHING in that
+                # case, so the still-running old plan keeps every prefetch
+                # it queued.
+                return
+            with self.async_exec.state_lock:
+                blocking = [k for k in self.async_exec.inflight
+                            if k not in self.async_exec.poisoned]
+            if blocking:
+                return       # e.g. a swap-out raced in: next point
+            self.stats.canceled_swap_ins += len(cancelled)
+        self.plan = plan
+        self.ctx.set_plan(plan)
+        self.stats.hot_swaps += 1
+        self._pending_plan = None
+        rec = self.engine.recorder
+        if rec is not None:
+            t = self.telemetry.now() if self.telemetry is not None else 0.0
+            rec.instant("hot_swap", t, job_id=self.ctx.job_id,
+                        site="safe-point", op_idx=idx)
 
     # ------------------------------------------------------------------
     def _name_of(self, v) -> str:
@@ -437,16 +463,24 @@ class JaxprExecutor:
                     _time.perf_counter() - t0, compressed=compressed, t=ts)
 
         written = self._writes.get(st, 0)
-        if self.async_exec:
-            # double-buffered stream: compute proceeds while the copy is
-            # on the wire; the device copy is retired at the next poll
-            # point, never before the copy lands (paper semantics kept —
-            # the ledger free happens only after completion)
-            done = self.async_exec.submit("out:" + st, do)
-            self._pending_out[st] = (done, compressed, written)
-            return
-        self.channel.transfer(do)
-        self._retire_out(st, compressed, written)
+        with span("tensile.swap_out", storage=st,
+                  bytes=self.ctx.size_of(st), compressed=compressed):
+            if self.async_exec:
+                # double-buffered stream: compute proceeds while the copy
+                # is on the wire; the device copy is retired at the next
+                # poll point, never before the copy lands (paper semantics
+                # kept — the ledger free happens only after completion)
+                done = self.async_exec.submit("out:" + st, do)
+                self._pending_out[st] = (done, compressed, written)
+                return
+            self._transfer(do)
+            self._retire_out(st, compressed, written)
+
+    def _transfer(self, fn) -> None:
+        """A copy on this thread, through the channel, timed as transfer."""
+        t0 = _time.perf_counter()
+        self.channel.transfer(fn)
+        self.stats.transfer_s += _time.perf_counter() - t0
 
     def _retire_out(self, st: str, compressed: bool, written: int) -> None:
         """A swap-out's copy has landed: record, free the device copy,
@@ -459,6 +493,7 @@ class JaxprExecutor:
         self.engine.record("swap_out", self.ctx, st)
         self._drop_storage(st)
         self.stats.swap_out_count += 1
+        self.stats.swap_out_bytes += self.ctx.size_of(st)
         if compressed:
             self.stats.compressed_swaps += 1
 
@@ -466,13 +501,17 @@ class JaxprExecutor:
         """Non-blocking completion poll of in-flight swap-outs (the other
         half of the double buffer): retire every copy that has landed.
         With ``block=True`` wait for all of them (drain / safe points)."""
-        for st, (done, compressed, written) in list(
-                self._pending_out.items()):
-            if block:
-                done.wait()
-            if done.is_set():
-                del self._pending_out[st]
-                self._retire_out(st, compressed, written)
+        if not self._pending_out:
+            return
+        with span("tensile.retire", pending=len(self._pending_out),
+                  block=block):
+            for st, (done, compressed, written) in list(
+                    self._pending_out.items()):
+                if block:
+                    done.wait()
+                if done.is_set():
+                    del self._pending_out[st]
+                    self._retire_out(st, compressed, written)
 
     def _swap_in(self, name: str, passive: bool) -> bool:
         """Prefetch from host; returns False when there is nothing to fetch
@@ -497,19 +536,26 @@ class JaxprExecutor:
 
         self.engine.record("passive_in" if passive else "swap_in",
                            self.ctx, st)
-        if self.async_exec and not passive:
-            self.async_exec.submit("in:" + st, do)
-        else:
-            t0 = _time.perf_counter()
-            self.channel.transfer(do)
-            if passive:
-                self.stats.passive_swap_ins += 1
+        nbytes = self.ctx.size_of(st)
+        with span("tensile.swap_in", storage=st, bytes=nbytes,
+                  passive=passive):
+            if self.async_exec and not passive:
+                self.async_exec.submit("in:" + st, do)
+            elif passive:
+                # the executor waits for the operand: a stall, not a
+                # transfer of its own
+                t0 = _time.perf_counter()
+                self.channel.transfer(do)
                 stall = _time.perf_counter() - t0
+                self.stats.passive_swap_ins += 1
                 self.stats.stall_time_s += stall
                 if self.telemetry is not None:
                     self.telemetry.record_stall(
                         self.ctx.job_id, self._cur_idx, stall, "passive_in")
+            else:
+                self._transfer(do)
         self.stats.swap_in_count += 1
+        self.stats.swap_in_bytes += nbytes
         return True
 
     def _ensure_input(self, name: str) -> None:
@@ -522,26 +568,38 @@ class JaxprExecutor:
                                        prefetch_inflight=inflight)
         if action is INPUT_RESIDENT:
             return
-        if action is INPUT_AWAIT_PREFETCH:
-            ts = _time.perf_counter()
-            self.async_exec.inflight["in:" + st].wait()
-            stall = _time.perf_counter() - ts
-            self.stats.stall_time_s += stall
-            if self.telemetry is not None:
-                self.telemetry.record_stall(
-                    self.ctx.job_id, self._cur_idx, stall, "await_prefetch")
-            if st in self.device:
+        with span("tensile.ensure", storage=st, eqn=self._cur_idx):
+            if action is INPUT_AWAIT_PREFETCH:
+                ts = _time.perf_counter()
+                self.async_exec.inflight["in:" + st].wait()
+                stall = _time.perf_counter() - ts
+                self.stats.stall_time_s += stall
+                if self.telemetry is not None:
+                    self.telemetry.record_stall(
+                        self.ctx.job_id, self._cur_idx, stall,
+                        "await_prefetch")
+                if st in self.device:
+                    return
+                action = self.ctx.input_action(self.resident, name)
+            if (action is INPUT_PASSIVE_SWAP_IN
+                    and self._swap_in(st, passive=True)):
                 return
-            action = self.ctx.input_action(self.resident, name)
-        if action is INPUT_PASSIVE_SWAP_IN and self._swap_in(st, passive=True):
-            return
-        self._recompute(name)
+            self._recompute(name)
 
     def _recompute(self, name: str) -> None:
         eqn_idx = self.producer.get(name)
         if eqn_idx is None:
             raise KeyError(f"tensor {name} unavailable and has no producer")
         eqn = self.jaxpr.eqns[eqn_idx]
+        with span("tensile.recompute", storage=self._st(name), eqn=eqn_idx):
+            outs, _ = self._dispatch(eqn_idx, self._invals(eqn))
+            for v, o in zip(eqn.outvars, outs):
+                if not _is_dropvar(v):
+                    self._put_device(self._name_of(v), o)
+        self.stats.recompute_count += 1
+
+    def _invals(self, eqn) -> List[Any]:
+        """An equation's operands, each brought onto the device."""
         invals = []
         for v in eqn.invars:
             if isinstance(v, jcore.Literal):
@@ -550,11 +608,18 @@ class JaxprExecutor:
             nm = self._name_of(v)
             self._ensure_input(nm)
             invals.append(self._get(nm))
-        outs = _eval_eqn(eqn, invals)
-        for v, o in zip(eqn.outvars, outs):
-            if not _is_dropvar(v):
-                self._put_device(self._name_of(v), o)
-        self.stats.recompute_count += 1
+        return invals
+
+    def _dispatch(self, idx: int, invals: List[Any]) -> Tuple[list, float]:
+        """Bind equation ``idx``: its results and the seconds the bind
+        took, which count as dispatch (the span's own cost does not)."""
+        eqn = self.jaxpr.eqns[idx]
+        with span("tensile.dispatch", prim=eqn.primitive.name, eqn=idx):
+            t0 = _time.perf_counter()
+            outs = _eval_eqn(eqn, invals)
+            dt = _time.perf_counter() - t0
+        self.stats.dispatch_s += dt
+        return outs, dt
 
     # ------------------------------------------------------------------
     def run(self, *args: Any) -> Any:
@@ -566,65 +631,51 @@ class JaxprExecutor:
         caller that keeps no other reference lets a swap-out or release
         free the device memory the plan says it frees."""
         t_start = _time.perf_counter()
-        res_start = 0
-        if self.telemetry is not None:
-            res_start = len(
-                self.telemetry.residency.get(self.ctx.job_id, ()))
-        # absorb host values preloaded by the controller between iterations
-        self.ctx.host |= set(self.host)
-        assert len(flat) == len(self.jaxpr.invars), \
-            f"expected {len(self.jaxpr.invars)} leaves, got {len(flat)}"
-        for v, val in zip(self.jaxpr.invars, flat):
-            nm = self._name_of(v)
-            st = self._st(nm)
-            if st in self.host_resident_inputs:
-                # previous iteration parked this storage on host; it enters
-                # the device only via its planned swap-in (or passively)
-                self._host_put(st, np.asarray(val), compressed=False)
-            else:
-                self._put_device(nm, val)
-        flat.clear()
-        val = None  # nor may the loop variable pin the last input
-        for v, val in zip(self.jaxpr.constvars, self.closed.consts):
-            self._put_device(self._name_of(v), val)
+        with span("tensile.iteration", job=self.ctx.job_id,
+                  iteration=self.iteration, plan_version=self.plan_version):
+            outs = self._iterate(flat)
+        st = self.stats
+        st.wall_time_s = _time.perf_counter() - t_start
+        st.self_s = st.wall_time_s - (st.dispatch_s + st.sync_s
+                                      + st.transfer_s + st.stall_time_s)
+        st.peak_bytes = self.accountant.peak
+        return outs
 
-        measure = self.measure_latency or self.telemetry is not None
-        if self.telemetry is not None:
+    def _iterate(self, flat: List[Any]) -> List[Any]:
+        hub = self.telemetry
+        res_start = 0
+        if hub is not None:
+            res_start = len(hub.residency.get(self.ctx.job_id, ()))
+        with span("tensile.place_inputs"):
+            self._place_inputs(flat)
+
+        if hub is not None:
             # hot path: telemetry appends go through a per-thread buffer
             # flushed once per op boundary (one lock round-trip per op
             # instead of one per record)
-            self.telemetry.begin_buffering()
+            hub.begin_buffering()
         for idx, eqn in enumerate(self.jaxpr.eqns):
             self._cur_idx = idx
             # retire any swap-out whose copy landed while we computed
             self._poll_swap_outs()
-            t0 = _time.perf_counter()
-            invals = []
-            for v in eqn.invars:
-                if isinstance(v, jcore.Literal):
-                    invals.append(v.val)
-                    continue
-                nm = self._name_of(v)
-                self._ensure_input(nm)
-                invals.append(self._get(nm))
-            t1 = _time.perf_counter()
-            outs = _eval_eqn(eqn, invals)
-            if measure:
-                jax.block_until_ready(outs)
-                t2 = _time.perf_counter()
-                if self.measure_latency:
-                    self.stats.op_latencies.append(t2 - t0)
-                if self.telemetry is not None:
-                    # compute-only latency: input-ensure time is reported
-                    # separately as stall records, so calibration samples
-                    # are not polluted by memory waits
-                    op = (self.seq.operators[idx]
-                          if idx < len(self.seq.operators) else None)
-                    self.telemetry.record_op(
-                        self.ctx.job_id, idx, t2 - t1,
-                        prim=eqn.primitive.name,
-                        flops=op.flops if op else 0.0,
-                        bytes_accessed=op.bytes_accessed if op else 0.0)
+            outs, bind_s = self._dispatch(idx, self._invals(eqn))
+            if hub is not None:
+                with span("tensile.sync", eqn=idx):
+                    t0 = _time.perf_counter()
+                    jax.block_until_ready(outs)
+                    sync_s = _time.perf_counter() - t0
+                self.stats.sync_s += sync_s
+                # compute-only latency, the bind and the wait for its
+                # results: input-ensure time is reported separately as
+                # stall records, so calibration samples are not polluted
+                # by memory waits
+                op = (self.seq.operators[idx]
+                      if idx < len(self.seq.operators) else None)
+                hub.record_op(
+                    self.ctx.job_id, idx, bind_s + sync_s,
+                    prim=eqn.primitive.name,
+                    flops=op.flops if op else 0.0,
+                    bytes_accessed=op.bytes_accessed if op else 0.0)
             for v, o in zip(eqn.outvars, outs):
                 # dropped results still occupy their buffer until the op's
                 # releases run — the allocator model both runtimes share
@@ -658,14 +709,43 @@ class JaxprExecutor:
             # preemptive arbitration: splice a pending plan in at a safe
             # point (after this op's events, before the next op)
             self._maybe_hot_swap(idx)
-            if self.telemetry is not None:
-                self.telemetry.flush()
+            if hub is not None:
+                hub.flush()
 
         if self.async_exec:
             self.async_exec.drain()
         self._poll_swap_outs(block=True)
-        if self.telemetry is not None:
-            self.telemetry.end_buffering()
+        if hub is not None:
+            hub.end_buffering()
+        with span("tensile.fetch_outputs"):
+            outs = self._fetch_outputs()
+        if hub is not None:
+            self.stats.residency_timeline = [
+                (r.t, r.resident_bytes)
+                for r in hub.residency.get(self.ctx.job_id, [])[res_start:]]
+            hub.end_iteration(self.ctx.job_id)
+        return outs
+
+    def _place_inputs(self, flat: List[Any]) -> None:
+        # absorb host values preloaded by the controller between iterations
+        self.ctx.host |= set(self.host)
+        assert len(flat) == len(self.jaxpr.invars), \
+            f"expected {len(self.jaxpr.invars)} leaves, got {len(flat)}"
+        for v, val in zip(self.jaxpr.invars, flat):
+            nm = self._name_of(v)
+            st = self._st(nm)
+            if st in self.host_resident_inputs:
+                # previous iteration parked this storage on host; it enters
+                # the device only via its planned swap-in (or passively)
+                self._host_put(st, np.asarray(val), compressed=False)
+            else:
+                self._put_device(nm, val)
+        flat.clear()
+        val = None  # nor may the loop variable pin the last input
+        for v, val in zip(self.jaxpr.constvars, self.closed.consts):
+            self._put_device(self._name_of(v), val)
+
+    def _fetch_outputs(self) -> List[Any]:
         # fetching outputs back to Python is harness work, not part of the
         # modeled iteration (steady state leaves swapped outputs on host) —
         # pause the trace (and telemetry) for it, resume afterwards
@@ -686,13 +766,6 @@ class JaxprExecutor:
             self.engine.trace.paused = False
         if self.telemetry is not None:
             self.telemetry.paused = False
-            self.stats.residency_timeline = [
-                (r.t, r.resident_bytes)
-                for r in self.telemetry.residency.get(
-                    self.ctx.job_id, [])[res_start:]]
-            self.telemetry.end_iteration(self.ctx.job_id)
-        self.stats.wall_time_s = _time.perf_counter() - t_start
-        self.stats.peak_bytes = self.accountant.peak
         return outs
 
     # ------------------------------------------------------------------

@@ -42,6 +42,7 @@ import traceback
 import warnings
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
+from ..obs.spans import gc_spans, span
 from .access import AccessSequence
 from .cost_model import CostModel, EWMATracker
 from .engine import (DeviceLedger, DmaChannel, JobLedgerView, MemoryEngine,
@@ -674,6 +675,10 @@ class GlobalController:
         live = [j for j, h in self.jobs.items() if not h.done]
         if not live:
             return
+        with span("tensile.replan", jobs=len(live)):
+            self._replan_live(live)
+
+    def _replan_live(self, live: List[str]) -> None:
         budgets: Optional[Dict[str, int]] = None
         prev_assignment: Dict[str, int] = {}
         if self.arbiter is not None:
@@ -739,10 +744,11 @@ class GlobalController:
             if not future:
                 continue            # iteration nearly over: boundary covers it
             try:
-                res = self.scheduler.replan_from(
-                    j, running if running is not None
-                    else SchedulingPlan(job_id=j),
-                    future[0], budgets[j])
+                with span("tensile.replan", job=j, preempt=True):
+                    res = self.scheduler.replan_from(
+                        j, running if running is not None
+                        else SchedulingPlan(job_id=j),
+                        future[0], budgets[j])
             except Exception as e:  # noqa: BLE001 - victim keeps its plan
                 self.preempt_failures.append((j, e))
                 self.events.warn("preempt",
@@ -759,79 +765,9 @@ class GlobalController:
 
     # ------------------------------------------------------------------
     def _run_job(self, handle: JobHandle) -> None:
-        import jax
         try:
-            # the executor owns the job's state while it runs: nothing
-            # else may hold the arrays, or a swap-out or release would
-            # free ledger bytes but not device memory
-            params, opt_state, batch = handle.args
-            handle.args = None
-            p_def = jax.tree.structure(params)
-            o_def = jax.tree.structure(opt_state)
-            n_p, n_o = p_def.num_leaves, o_def.num_leaves
-            state = jax.tree.leaves((params, opt_state))
-            del params, opt_state
-            version_used = -1
-            ex: Optional[JaxprExecutor] = None
-            for it in range(handle.iterations):
-                with self._lock:
-                    plan = handle.plan
-                    version = handle.plan_version
-                if ex is None or version != version_used:
-                    if ex is not None:
-                        ex.close()
-                    # carry the host store across plan versions
-                    old_host = ex.host if ex is not None else {}
-                    old_compressed = (set(ex.ctx.host_compressed)
-                                      if ex is not None else set())
-                    ex = JaxprExecutor(
-                        handle.closed_jaxpr, handle.seq, plan,
-                        accountant=self.accountant, channel=self.channel,
-                        async_swap=self.async_swap, measure_latency=True,
-                        telemetry=self.telemetry)
-                    ex.host.update(old_host)
-                    ex.ctx.host_compressed |= old_compressed
-                    version_used = version
-                    handle.executor = ex
-                else:
-                    # fresh per-iteration stores, persistent host cache
-                    # (incl. which parked copies are quantized — fetching
-                    # them must go through the dequantize path)
-                    host = ex.host
-                    compressed = set(ex.ctx.host_compressed)
-                    ex = JaxprExecutor(
-                        handle.closed_jaxpr, handle.seq, plan,
-                        accountant=self.accountant, channel=self.channel,
-                        async_swap=self.async_swap, measure_latency=True,
-                        telemetry=self.telemetry)
-                    ex.host.update(host)
-                    ex.ctx.host_compressed |= compressed
-                    handle.executor = ex
-                t0 = _time.perf_counter()
-                # run_flat empties the list it is given: after this line no
-                # reference to the iteration's input state is left here
-                state.extend(jax.tree.leaves(batch))
-                outs = ex.run_flat(state)
-                handle.step_times.append(_time.perf_counter() - t0)
-                handle.stats.append(ex.stats)
-                handle.peak_bytes = max(handle.peak_bytes, ex.stats.peak_bytes)
-                # feed params/opt-state back (outputs 0,1 by convention);
-                # the rest (the loss) is what the step reports
-                state = outs[:n_p + n_o]
-                handle.outputs.append(outs[n_p + n_o:])
-                del outs
-                # measured-telemetry feedback (paper step 4): the hub
-                # already holds this iteration's op samples; fold them
-                # into the job's sequence and replan on HUB-reported
-                # drift (the scheduler-private EWMA path stays available
-                # as report_latencies for embedders without a hub)
-                drift = self.report_telemetry(handle.job_id)
-                if drift:
-                    with self._lock:
-                        self._replan()
-                ex.close()
-            handle.args = (jax.tree.unflatten(p_def, state[:n_p]),
-                           jax.tree.unflatten(o_def, state[n_p:]), batch)
+            with gc_spans():
+                self._iterate_job(handle)
         except BaseException as e:  # noqa: BLE001 - surfaced via wait()
             handle.error = e
             handle.error_tb = traceback.format_exc()
@@ -840,6 +776,79 @@ class GlobalController:
             # outside the job's own try: a failure while replanning the
             # SURVIVORS must not blame this (possibly successful) job
             self._on_job_exit(handle)
+
+    def _iterate_job(self, handle: JobHandle) -> None:
+        import jax
+
+        # the executor owns the job's state while it runs: nothing else
+        # may hold the arrays, or a swap-out or release would free ledger
+        # bytes but not device memory
+        params, opt_state, batch = handle.args
+        handle.args = None
+        p_def = jax.tree.structure(params)
+        o_def = jax.tree.structure(opt_state)
+        n_p, n_o = p_def.num_leaves, o_def.num_leaves
+        state = jax.tree.leaves((params, opt_state))
+        del params, opt_state
+        ex: Optional[JaxprExecutor] = None
+        # the controller's time between two iterations, and its replans,
+        # go onto the second one's stats
+        t_ret: Optional[float] = None
+        replan_s, replans = 0.0, 0
+        for it in range(handle.iterations):
+            with self._lock:
+                plan = handle.plan
+                version = handle.plan_version
+            with span("tensile.executor_init", job=handle.job_id,
+                      iteration=it):
+                # fresh per-iteration stores; the host cache (and which
+                # parked copies are quantized — fetching them must go
+                # through the dequantize path) carries across iterations
+                # and plan versions
+                host = ex.host if ex is not None else {}
+                compressed = (set(ex.ctx.host_compressed)
+                              if ex is not None else set())
+                ex = JaxprExecutor(
+                    handle.closed_jaxpr, handle.seq, plan,
+                    accountant=self.accountant, channel=self.channel,
+                    async_swap=self.async_swap, telemetry=self.telemetry,
+                    iteration=it, plan_version=version)
+                ex.host.update(host)
+                ex.ctx.host_compressed |= compressed
+            handle.executor = ex
+            # run_flat empties the list it is given: after this line no
+            # reference to the iteration's input state is left here
+            state.extend(jax.tree.leaves(batch))
+            t0 = _time.perf_counter()
+            if t_ret is not None:
+                ex.stats.before_s = t0 - t_ret
+                ex.stats.replan_s, ex.stats.replans = replan_s, replans
+            outs = ex.run_flat(state)
+            t_ret = _time.perf_counter()
+            handle.step_times.append(t_ret - t0)
+            handle.stats.append(ex.stats)
+            handle.peak_bytes = max(handle.peak_bytes, ex.stats.peak_bytes)
+            # feed params/opt-state back (outputs 0,1 by convention); the
+            # rest (the loss) is what the step reports
+            state = outs[:n_p + n_o]
+            handle.outputs.append(outs[n_p + n_o:])
+            del outs
+            # measured-telemetry feedback (paper step 4): the hub already
+            # holds this iteration's op samples; fold them into the job's
+            # sequence and replan on HUB-reported drift (the
+            # scheduler-private EWMA path stays available as
+            # report_latencies for embedders without a hub)
+            with span("tensile.report_telemetry", job=handle.job_id):
+                drift = self.report_telemetry(handle.job_id)
+            replan_s, replans = 0.0, 0
+            if drift:
+                with self._lock:
+                    t = _time.perf_counter()
+                    self._replan()
+                    replan_s, replans = _time.perf_counter() - t, 1
+            ex.close()
+        handle.args = (jax.tree.unflatten(p_def, state[:n_p]),
+                       jax.tree.unflatten(o_def, state[n_p:]), batch)
 
     # ------------------------------------------------------------------
     def _on_job_exit(self, handle: JobHandle) -> None:

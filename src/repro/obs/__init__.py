@@ -1,6 +1,6 @@
 """The observability plane: a unified view over the telemetry plane.
 
-Three coordinated pieces (ISSUE 10):
+Four coordinated pieces:
 
 - :class:`TraceRecorder` — a structured span/instant/counter event stream
   tapped from the ``TelemetryHub`` / ``MemoryEngine`` / ``DmaChannel`` /
@@ -13,6 +13,8 @@ Three coordinated pieces (ISSUE 10):
   predicted peak/EOR/safe-point placement against measured values per
   fingerprint, emits drift gauges + WARN events past a threshold, and
   persists per-fingerprint drift history into the ``ExperienceStore``.
+- :func:`span` — the program's own spans (``tensile.*``, listed in
+  ``SPANS``) as profiler annotations, on the device trace's clock.
 
 Every producer-side hook is ZERO-overhead when no recorder is attached:
 one ``is not None`` check on an attribute that defaults to ``None`` —
@@ -21,6 +23,7 @@ the same discipline as the DMA channel's ``coalesce=False`` default.
 from .events import Event, EventLog
 from .drift import DriftMonitor, DriftSample
 from .metrics import MetricsRegistry, parse_metrics_text
+from .spans import SPANS, gc_spans, span
 from .trace import (TRACE_SCHEMA_VERSION, TraceRecorder, format_summary,
                     load_trace, summarize_trace, validate_chrome_trace)
 
@@ -28,6 +31,7 @@ __all__ = [
     "Event", "EventLog",
     "DriftMonitor", "DriftSample",
     "MetricsRegistry", "parse_metrics_text",
+    "SPANS", "gc_spans", "span",
     "TRACE_SCHEMA_VERSION", "TraceRecorder", "format_summary", "load_trace",
     "summarize_trace", "validate_chrome_trace",
 ]
