@@ -231,17 +231,19 @@ class CostModel:
         samples contract both constants geometrically toward the device's
         effective throughput.  Samples already consumed (per-job cursor)
         are skipped, so the controller can call this after every
-        iteration at O(new samples) cost.  Returns the post-update
-        ``calibration_report`` — unless ``report=False``, which keeps the
-        whole call O(new samples) for per-iteration callers (the report
-        re-scans every sample)."""
+        iteration at O(new samples) cost; so are cold samples, whose
+        latency holds a compilation (``OpSample.cold``).  Returns the
+        post-update ``calibration_report`` — unless ``report=False``, which
+        keeps the whole call O(new samples) for per-iteration callers (the
+        report re-scans every sample)."""
         c = self.calib
         for job_id in hub.jobs():
             samples = hub.ops.get(job_id, ())
             start = self._recal_cursor.get(job_id, 0)
             for s in samples[start:]:
                 eff = s.latency_s - c.overhead_s
-                if eff <= 0 or (s.flops <= 0 and s.bytes_accessed <= 0):
+                if s.cold or eff <= 0 or (s.flops <= 0
+                                          and s.bytes_accessed <= 0):
                     continue
                 if eff < 0.25 * s.latency_s:
                     # overhead-dominated sample: measurement jitter of
@@ -384,16 +386,18 @@ class EWMATracker:
         return new
 
     def ingest(self, hub, job_id: str) -> int:
-        """Fold every NEW TelemetryHub op sample of the job into the
-        tracker (per-job cursor, O(new samples)); returns how many were
-        consumed.  This is the hub-fed path of §IV-E — the tracker no
-        longer needs the executor to hand it latency lists directly."""
+        """Fold every NEW warm TelemetryHub op sample of the job into the
+        tracker (per-job cursor, O(new samples); cold samples, which hold
+        a compilation, are passed over); returns how many were folded.
+        This is the hub-fed path of §IV-E — the tracker no longer needs
+        the executor to hand it latency lists directly."""
         samples = hub.ops.get(job_id, ())
         start = self._hub_cursor.get(job_id, 0)
-        for s in samples[start:]:
+        warm = [s for s in samples[start:] if not s.cold]
+        for s in warm:
             self.update(s.op_idx, s.latency_s)
         self._hub_cursor[job_id] = len(samples)
-        return len(samples) - start
+        return len(warm)
 
     def drift_ratio(self, baseline_sum: float) -> float:
         s = sum(self.values.values())

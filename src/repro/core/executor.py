@@ -60,8 +60,10 @@ class ExecutionStats:
     when a telemetry hub is attached), ``transfer_s`` (inside the DMA
     channel, where ``stall_time_s`` does not already count it),
     ``stall_time_s`` (waiting for operands not on the device) and
-    ``self_s``, the rest: the executor's own bookkeeping.  The controller
-    fills the last three fields before the iteration starts."""
+    ``self_s``, the rest: the executor's own bookkeeping.  ``cold_ops``
+    counts the equations bound for the first time in the job, which
+    compile (their op samples are marked cold).  The controller fills the
+    last three fields before the iteration starts."""
     peak_bytes: int = 0
     wall_time_s: float = 0.0
     swap_out_count: int = 0
@@ -72,6 +74,7 @@ class ExecutionStats:
     passive_swap_ins: int = 0
     recompute_count: int = 0
     compressed_swaps: int = 0
+    cold_ops: int = 0
     stall_time_s: float = 0.0
     dispatch_s: float = 0.0
     sync_s: float = 0.0
@@ -249,7 +252,8 @@ class JaxprExecutor:
                  host_resident_inputs: Optional[Set[str]] = None,
                  engine: Optional[MemoryEngine] = None,
                  telemetry: Optional[TelemetryHub] = None,
-                 iteration: int = 0, plan_version: int = 0):
+                 iteration: int = 0, plan_version: int = 0,
+                 bound_eqns: Optional[Set[int]] = None):
         self.closed = closed_jaxpr
         self.jaxpr = closed_jaxpr.jaxpr
         self.seq = seq
@@ -266,6 +270,12 @@ class JaxprExecutor:
         # the job's iteration and plan version, for the iteration's span
         self.iteration = iteration
         self.plan_version = plan_version
+        # indices of the equations the job has bound before, in this
+        # executor or an earlier one of the job (the set is the caller's
+        # and grows in place): a first bind traces and compiles, later
+        # ones run the executable JAX keeps
+        self.bound_eqns: Set[int] = (bound_eqns if bound_eqns is not None
+                                     else set())
         # storages whose *input* value starts on host (previous iteration's
         # cross-iteration swap-out; paper Fig. 1(c) steady state)
         self.host_resident_inputs: Set[str] = set(host_resident_inputs or ())
@@ -592,7 +602,7 @@ class JaxprExecutor:
             raise KeyError(f"tensor {name} unavailable and has no producer")
         eqn = self.jaxpr.eqns[eqn_idx]
         with span("tensile.recompute", storage=self._st(name), eqn=eqn_idx):
-            outs, _ = self._dispatch(eqn_idx, self._invals(eqn))
+            outs, _, _ = self._dispatch(eqn_idx, self._invals(eqn))
             for v, o in zip(eqn.outvars, outs):
                 if not _is_dropvar(v):
                     self._put_device(self._name_of(v), o)
@@ -610,16 +620,21 @@ class JaxprExecutor:
             invals.append(self._get(nm))
         return invals
 
-    def _dispatch(self, idx: int, invals: List[Any]) -> Tuple[list, float]:
-        """Bind equation ``idx``: its results and the seconds the bind
-        took, which count as dispatch (the span's own cost does not)."""
+    def _dispatch(self, idx: int,
+                  invals: List[Any]) -> Tuple[list, float, bool]:
+        """Bind equation ``idx``: its results, the seconds the bind took,
+        which count as dispatch (the span's own cost does not), and
+        whether it was the job's first bind of the equation (cold)."""
         eqn = self.jaxpr.eqns[idx]
-        with span("tensile.dispatch", prim=eqn.primitive.name, eqn=idx):
+        cold = idx not in self.bound_eqns
+        with span("tensile.dispatch", prim=eqn.primitive.name, eqn=idx,
+                  cold=cold):
             t0 = _time.perf_counter()
             outs = _eval_eqn(eqn, invals)
             dt = _time.perf_counter() - t0
+        self.bound_eqns.add(idx)
         self.stats.dispatch_s += dt
-        return outs, dt
+        return outs, dt, cold
 
     # ------------------------------------------------------------------
     def run(self, *args: Any) -> Any:
@@ -658,7 +673,8 @@ class JaxprExecutor:
             self._cur_idx = idx
             # retire any swap-out whose copy landed while we computed
             self._poll_swap_outs()
-            outs, bind_s = self._dispatch(idx, self._invals(eqn))
+            outs, bind_s, cold = self._dispatch(idx, self._invals(eqn))
+            self.stats.cold_ops += cold
             if hub is not None:
                 with span("tensile.sync", eqn=idx):
                     t0 = _time.perf_counter()
@@ -668,14 +684,16 @@ class JaxprExecutor:
                 # compute-only latency, the bind and the wait for its
                 # results: input-ensure time is reported separately as
                 # stall records, so calibration samples are not polluted
-                # by memory waits
+                # by memory waits.  A cold sample holds the compilation:
+                # the hub keeps it out of the latencies plans are made from
                 op = (self.seq.operators[idx]
                       if idx < len(self.seq.operators) else None)
                 hub.record_op(
                     self.ctx.job_id, idx, bind_s + sync_s,
                     prim=eqn.primitive.name,
                     flops=op.flops if op else 0.0,
-                    bytes_accessed=op.bytes_accessed if op else 0.0)
+                    bytes_accessed=op.bytes_accessed if op else 0.0,
+                    cold=cold)
             for v, o in zip(eqn.outvars, outs):
                 # dropped results still occupy their buffer until the op's
                 # releases run — the allocator model both runtimes share

@@ -40,7 +40,8 @@ import threading
 import time as _time
 import traceback
 import warnings
-from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
+from typing import (Any, Callable, Dict, List, Optional, Sequence, Set,
+                    Tuple)
 
 from ..obs.spans import gc_spans, span
 from .access import AccessSequence
@@ -112,6 +113,9 @@ class JobHandle:
     # per iteration, the step's outputs after the new params and
     # optimizer state (a train step's loss)
     outputs: List[Any] = dataclasses.field(default_factory=list)
+    # indices of the equations the job's executors have bound: each
+    # executor's first bind of one not in it is cold (it compiles)
+    bound_eqns: Set[int] = dataclasses.field(default_factory=set)
 
     @property
     def budget_bytes(self) -> Optional[int]:
@@ -812,7 +816,8 @@ class GlobalController:
                     handle.closed_jaxpr, handle.seq, plan,
                     accountant=self.accountant, channel=self.channel,
                     async_swap=self.async_swap, telemetry=self.telemetry,
-                    iteration=it, plan_version=version)
+                    iteration=it, plan_version=version,
+                    bound_eqns=handle.bound_eqns)
                 ex.host.update(host)
                 ex.ctx.host_compressed |= compressed
             handle.executor = ex

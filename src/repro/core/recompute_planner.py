@@ -4,8 +4,8 @@ Driven by ``passes.RecomputePass`` under the Pipeline's convergence loop:
 runs after swapping is exhausted (pass order) and only if the predicted peak
 still exceeds the memory budget (the pass's gate).  Candidates are restricted to tensors that have **never
 been released or swapped** (so a recomputation never cascades into further
-swap-ins/recomputes), whose producer's inputs are still resident at the
-recompute instant.  Candidates are ranked by Capuchin's MSPS metric:
+swap-ins/recomputes), whose producer's inputs are still resident when
+the recompute runs.  Candidates are ranked by Capuchin's MSPS metric:
 
     MSPS = memory_saving / recomputation_time
 """
@@ -64,22 +64,27 @@ class RecomputePlanner:
             touched.add(e.tensor_id)
         return touched
 
-    def _inputs_resident_at(self, op_idx: int, when: float,
+    def _inputs_resident_at(self, op_idx: int, target_op: int,
                             touched: set) -> bool:
-        """All producer inputs must still be resident at the recompute
-        instant: persistent, or activations whose last use is later and which
-        are untouched by the plan."""
+        """All producer inputs must still be resident when the recompute
+        runs, after the releases of its trigger op (``target_op - 1``):
+        parameters and optimizer state untouched by the plan, or tensors
+        used again at or after ``target_op`` and untouched.  A step input
+        is freed at its last use like an activation.  An input last used
+        by the trigger op itself is freed before the recompute, which
+        would then have to regenerate it, and so on back up the graph to
+        a step input that nothing can."""
         op = self.seq.operators[op_idx]
         for tid in op.inputs:
             spec = self.seq.tensors.get(tid)
             if spec is None:
                 continue
-            if spec.kind in PERSISTENT_KINDS or spec.kind is TensorKind.INPUT:
+            if spec.kind in PERSISTENT_KINDS:
                 if tid in touched:
                     return False
                 continue
             last = self.seq.last_access(tid)
-            if last is None or last.end_time < when or tid in touched:
+            if last is None or last.op_idx < target_op or tid in touched:
                 return False
         return True
 
@@ -124,7 +129,8 @@ class RecomputePlanner:
                 cursor = a
             if target is None:
                 continue
-            if not self._inputs_resident_at(tga.op_idx, target.time, touched):
+            if not self._inputs_resident_at(tga.op_idx, target.op_idx,
+                                            touched):
                 continue
             out.append(RecomputeCandidate(
                 tensor_id=tid, job_id=seq.job_id, size_bytes=spec.size_bytes,

@@ -10,7 +10,8 @@ all ran on modeled numbers.  ``TelemetryHub`` makes measurement a
 first-class plane of the architecture:
 
   producers (one record schema, two clocks)
-    * ``JaxprExecutor``  — per-op wall-clock latencies, per-transfer DMA
+    * ``JaxprExecutor``  — per-op wall-clock latencies (a job's first
+      execution of each equation marked cold: it compiles), per-transfer DMA
       durations (full-precision and compressed), stall events, and the
       per-job residency timeline (via the shared ``DeviceLedger`` hook),
       all in *real* time.
@@ -55,7 +56,16 @@ _EPS = 1e-12
 @dataclasses.dataclass
 class OpSample:
     """One operator execution: measured latency + the static cost-model
-    features (flops / bytes) needed to recalibrate throughput constants."""
+    features (flops / bytes) needed to recalibrate throughput constants.
+
+    ``cold`` marks the job's first execution of the equation: its latency
+    holds tracing and compiling (or loading) the equation's executable,
+    not the op's steady cost.  A cold sample stays in ``ops`` (traces,
+    ``calibration_report``, ``op_summary``, stall shares, safe-point
+    timing) but is left out of every consumer that turns samples into
+    plan latencies: the hub's EWMA (``op_latencies``, ``latency_sum``,
+    ``drift_ratio``), ``CostModel.recalibrate`` and
+    ``EWMATracker.ingest``.  The simulator never marks one."""
 
     job_id: str
     iteration: int
@@ -65,6 +75,7 @@ class OpSample:
     flops: float
     bytes_accessed: float
     t: float                 # instant the op COMPLETED
+    cold: bool = False
 
 
 @dataclasses.dataclass
@@ -216,14 +227,17 @@ class TelemetryHub:
         self._local.buffer = None
 
     def _publish(self, kind: str, s) -> None:
-        """Append one stamped sample to its stream (hub lock held)."""
+        """Append one stamped sample to its stream (hub lock held).  An op
+        sample is folded into the job's EWMA unless it is cold: a cold
+        one is kept in ``ops`` alone."""
         if kind == "op":
             self.ops.setdefault(s.job_id, []).append(s)
-            ew = self._ewma.setdefault(s.job_id, {})
-            old = ew.get(s.op_idx)
-            ew[s.op_idx] = s.latency_s if old is None else (
-                self.ewma_alpha * s.latency_s
-                + (1 - self.ewma_alpha) * old)
+            if not s.cold:
+                ew = self._ewma.setdefault(s.job_id, {})
+                old = ew.get(s.op_idx)
+                ew[s.op_idx] = s.latency_s if old is None else (
+                    self.ewma_alpha * s.latency_s
+                    + (1 - self.ewma_alpha) * old)
         elif kind == "transfer":
             self.transfers.setdefault(s.job_id, []).append(s)
         elif kind == "stall":
@@ -248,11 +262,15 @@ class TelemetryHub:
     def record_op(self, job_id: str, op_idx: int, latency_s: float,
                   prim: str = "", flops: float = 0.0,
                   bytes_accessed: float = 0.0,
-                  t: Optional[float] = None) -> None:
+                  t: Optional[float] = None, cold: bool = False) -> None:
+        """One op's measured latency.  ``cold`` marks the job's first
+        execution of the equation (its latency holds the compilation):
+        the sample is kept in ``ops`` but never folded into the EWMA
+        latencies plans are made from (see ``OpSample``)."""
         if self.paused:
             return
         s = OpSample(job_id, self._it(job_id), op_idx, prim, latency_s,
-                     flops, bytes_accessed, self._stamp(t))
+                     flops, bytes_accessed, self._stamp(t), cold)
         buf = self._buffer()
         if buf is not None:
             buf.append(("op", s))
@@ -353,7 +371,8 @@ class TelemetryHub:
         return acc
 
     def op_latencies(self, job_id: str) -> Dict[int, float]:
-        """EWMA-corrected measured latency per op index (§IV-E)."""
+        """EWMA-corrected measured latency per op index (§IV-E), from
+        warm samples only: an op with only a cold sample has none."""
         with self._lock:
             return dict(self._ewma.get(job_id, {}))
 
@@ -419,8 +438,9 @@ class TelemetryHub:
         return n, tot_b, tot_s
 
     def total_op_samples(self) -> int:
-        """Hub-wide op-sample count, read under the hub lock (callers
-        must not iterate ``ops`` themselves while producers insert)."""
+        """Hub-wide op-sample count, cold samples included, read under
+        the hub lock (callers must not iterate ``ops`` themselves while
+        producers insert)."""
         with self._lock:
             return sum(len(v) for v in self.ops.values())
 

@@ -212,7 +212,7 @@ def test_buffered_telemetry_identical_to_unbuffered():
 def test_record_schemas_are_pinned():
     assert record_schemas() == {
         "op": ("job_id", "iteration", "op_idx", "prim", "latency_s",
-               "flops", "bytes_accessed", "t"),
+               "flops", "bytes_accessed", "t", "cold"),
         "transfer": ("job_id", "iteration", "storage", "direction",
                      "size_bytes", "duration_s", "compressed", "passive",
                      "t"),

@@ -112,11 +112,15 @@ def test_controller_time_between_iterations():
     h = ctl.submit(JobSpec("j", iterations=3, payload=_payload()))
     ctl.wait(timeout=300)
     assert h.error is None and len(h.stats) == 3
-    first, *rest = h.stats
+    first, second, third = h.stats
     assert first.before_s == 0.0 and first.replans == 0
-    for st in rest:
-        assert st.replans >= 1
-        assert 0.0 < st.replan_s <= st.before_s
+    # the first iteration's op samples are cold (they compile), so the
+    # hub reports no drift after it and nothing is replanned
+    assert second.before_s > 0.0
+    assert second.replans == 0 and second.replan_s == 0.0
+    assert third.replans >= 1
+    assert 0.0 < third.replan_s <= third.before_s
+    for st in (second, third):
         assert st.self_s >= 0.0
         assert _parts(st) + st.self_s == pytest.approx(st.wall_time_s)
 
