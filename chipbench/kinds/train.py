@@ -68,8 +68,9 @@ def register_config(model: dict):
     from repro.configs import CONFIGS
     from repro.configs.base import LayerSpec, ModelConfig
     kw = dict(model)
-    if "block" in kw:
-        kw["block"] = tuple(LayerSpec(**b) for b in kw["block"])
+    for key in ("prefix", "block"):
+        if key in kw:
+            kw[key] = tuple(LayerSpec(**b) for b in kw[key])
     cfg = ModelConfig(**kw)
     CONFIGS[cfg.name] = cfg
     return cfg
@@ -308,9 +309,11 @@ class Window:
             # rows differ
             batch = win.feed(i)
             flat[len(flat) - len(batch):] = batch
-            with win.jax.profiler.TraceAnnotation("chipbench.train_iteration"):
+            ann = win.jax.profiler.TraceAnnotation
+            with ann("chipbench.train_iteration"):
                 out = orig(ex, flat)
-                win.jax.block_until_ready(out)
+                with ann("chipbench.step_outputs_wait"):
+                    win.jax.block_until_ready(out)
             win.iter = i
             if i <= win.n_check:
                 win.on_check(i, out)
@@ -505,7 +508,7 @@ def run(cell, *, seed: int, seconds: float, trace: bool, devices, peak,
         train_tokens_per_s=steps * tokens_per_step / window_s,
         planned_peak_bytes=plan.planned_peak_bytes if plan else 0,
         swap_outs=[s.swap_out_count for s in stats],
-        stall_s=[s.stall_time_s for s in stats],
+        stall_s=[s.stall_time_s for s in stats], stats=stats,
         flops_per_token=ref.train_flops_per_token(model, mix["seq_len"]),
         peak_flops=peak["bf16_flops_per_s"], trace=None)
 
@@ -519,6 +522,9 @@ def run(cell, *, seed: int, seconds: float, trace: bool, devices, peak,
         layer.trace = tracing.summarize(tracing.load_events(
             tracing.find_xplane(trace_dir)))
         shutil.rmtree(trace_dir, ignore_errors=True)
+        top = sorted(layer.trace.scope_seconds.items(), key=lambda kv: -kv[1])
+        log(f"[trace] busy {layer.trace.busy_s:.3f} s; device seconds by "
+            f"scope: {[(k, round(v, 4)) for k, v in top[:tracing.TOP]]}")
 
     # -- the comparison
     want = reference_numbers(cell, seed, log=log)

@@ -1,0 +1,8 @@
+"""Share of the window (%) the executor spent binding equations, those of
+recomputes too (ExecutionStats.dispatch_s, host clock): executor layer."""
+
+
+def read(run):
+    if getattr(run, "kind", None) != "train" or not run.window_s:
+        return None
+    return 100.0 * sum(s.dispatch_s for s in run.stats) / run.window_s

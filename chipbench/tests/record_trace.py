@@ -2,10 +2,14 @@
 
     python3 chipbench/tests/record_trace.py <out.json.gz>
 
-Inside the window marks: three annotated steps of a few device
-operations, with host sleeps of known length between them, so that the
-trace has device time, idle gaps and host annotations to name them.
-Only the window's events are kept, as ``trace.Events`` in gzipped JSON.
+Inside the window marks: three annotated steps, with host sleeps of known
+length between them, so that the trace has device time, idle gaps and
+host annotations to name them.  Each step runs a jitted scan over four
+layers whose parts are traced under ``jax.named_scope("attn")`` and
+``"mlp"``, as a model's layer stack is, then binds the equations of a
+small jaxpr one by one, as the program's executor does, with the first
+of them under ``"eager"``.  Only the window's events are kept, as
+``trace.Events`` in gzipped JSON.
 """
 from __future__ import annotations
 
@@ -14,7 +18,10 @@ import tempfile
 import time
 from pathlib import Path
 
-sys.path.insert(0, str(Path(__file__).resolve().parents[2]))
+ROOT = Path(__file__).resolve().parents[2]
+for _p in (str(ROOT), str(ROOT / "src")):
+    if _p not in sys.path:
+        sys.path.insert(0, _p)
 
 from chipbench import trace as tracing  # noqa: E402
 
@@ -25,23 +32,44 @@ def main(out: str) -> int:
     import jax
     import jax.numpy as jnp
 
+    from repro.core.executor import _eval_eqn
+
     if jax.devices()[0].platform == "cpu":
         print("record_trace: needs an accelerator", file=sys.stderr)
         return 3
     ann = jax.profiler.TraceAnnotation
+
+    def layer(a, _):
+        with jax.named_scope("attn"):
+            a = jnp.tanh(a @ a)
+        with jax.named_scope("mlp"):
+            a = (a @ a) * 0.5
+        return a, None
+
+    def small(a):
+        with jax.named_scope("eager"):
+            a = a @ a
+        return a * 0.5
+
     x = jnp.ones((2048, 2048), jnp.bfloat16)
-    mm = jax.jit(lambda a: (a @ a) * 0.5)
-    mm(x).block_until_ready()
+    stack = jax.jit(lambda a: jax.lax.scan(layer, a, None, length=4)[0])
+    closed = jax.make_jaxpr(small)(x)
+
+    def eager(a):
+        env = dict(zip(closed.jaxpr.invars, [a]))
+        for eqn in closed.jaxpr.eqns:
+            outs = _eval_eqn(eqn, [env[v] for v in eqn.invars])
+            env.update(zip(eqn.outvars, outs))
+        return env[closed.jaxpr.outvars[0]]
+
+    jax.block_until_ready((stack(x), eager(x)))
     d = tempfile.mkdtemp()
     jax.profiler.start_trace(d, profiler_options=tracing.profiler_options())
     with ann(tracing.OPEN):
         pass
     for i, pause in enumerate(SLEEP_S):
         with ann("record.step"):
-            y = x
-            for _ in range(4):
-                y = mm(y)
-            y.block_until_ready()
+            jax.block_until_ready(eager(stack(x)))
         with ann(f"record.sleep{i}"):
             time.sleep(pause)
     with ann(tracing.CLOSE):
@@ -61,6 +89,10 @@ def main(out: str) -> int:
     tracing.save_events(kept, out)
     s = tracing.summarize(kept)
     print(f"busy {s.busy_s} s of {s.window_s} s; {s.breakdown}")
+    print(f"scope_seconds {s.scope_seconds}")
+    for p, ops in kept.device_ops.items():
+        for e in ops:
+            print(p, e)
     return 0
 
 

@@ -35,6 +35,33 @@ def test_config_registers_with_its_parameter_count(name):
         assert doc["source_config"][key] == change["source"] != change["here"]
 
 
+def test_prefix_layers_register():
+    """A leading dense layer (``prefix``) before a block of MoE layers
+    registers as LayerSpecs, and the count matches the program's tree,
+    which adds the final norm."""
+    import math
+
+    import jax
+
+    from repro.configs.base import LayerSpec
+    from repro.models.registry import get_model
+    model = {"name": "tiny-prefix-moe", "arch_family": "moe", "n_layers": 3,
+             "d_model": 64, "n_heads": 4, "n_kv_heads": 2, "head_dim": 16,
+             "d_ff": 32, "vocab_size": 512, "n_experts": 4, "top_k": 2,
+             "n_shared_experts": 1, "prefix_d_ff": 96,
+             "prefix": [{"mixer": "attn", "ffn": "dense"}],
+             "block": [{"mixer": "attn", "ffn": "moe"}]}
+    cfg = register_config(model)
+    assert cfg.prefix == (LayerSpec("attn", "dense"),)
+    assert cfg.block == (LayerSpec("attn", "moe"),)
+    # embeddings 65,536; the dense layer 12,288 + 18,432 + 128; two MoE
+    # layers of 12,288 + 5 x 6,144 + 256 + 128
+    assert cfg.param_count() == 183_168
+    tree, _ = get_model(cfg).abstract_params()
+    assert sum(math.prod(x.shape) for x in jax.tree.leaves(tree)) \
+        == cfg.param_count() + model["d_model"]
+
+
 def test_benchmark_json_names_files_that_exist():
     bench = json.loads((ROOT / "BENCHMARK.json").read_text())
     assert set(bench) == {"command", "paths", "run_seconds", "configs",

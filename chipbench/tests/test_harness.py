@@ -5,6 +5,8 @@ on one chip can have.  Without an accelerator, or without the program
 beside it, the command exits non-zero and prints no result."""
 from __future__ import annotations
 
+import json
+import math
 import os
 import shutil
 import subprocess
@@ -13,10 +15,19 @@ import sys
 import pytest
 
 from chipbench import run as harness
+from chipbench import trace as tracing
+from chipbench.kinds import train
 from chipbench.tests.tiny import make_root
 
 CELL = "train.tiny-dense"
 SEED = 2 ** 31 + 77
+MS = 1e6
+# the readers of parts of the window that do not overlap: the
+# executor's wall time (dispatch, sync, transfer, stall, self) and the
+# controller's time between steps (replanning and the rest)
+SHARES = ("eqn_dispatch_share", "eqn_sync_share", "swap_transfer_share",
+          "swap_stall_share", "executor_self_share", "controller_share",
+          "replan_share")
 
 
 @pytest.fixture(scope="module")
@@ -34,8 +45,26 @@ def _run(root, **kw):
                        require_accelerator=False, **kw)
 
 
-def test_sound_run_is_correct(root):
-    out = _run(root)
+@pytest.fixture(scope="module")
+def sound(root, tmp_path_factory):
+    """One sound untraced run of the tiny cell: its result line and the
+    result of ``kinds/train.run``, whose ``layer`` the readers read."""
+    runs = []
+    train_run = train.run
+
+    def keep(cell, **kw):
+        runs.append(train_run(cell, **kw))
+        return runs[-1]
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("JAX_COMPILATION_CACHE_DIR",
+                  str(tmp_path_factory.mktemp("jc")))
+        mp.setattr(train, "run", keep)
+        return _run(root), runs[0]
+
+
+def test_sound_run_is_correct(sound):
+    out = sound[0]
     assert out["correct"] is True
     assert out["attempted"] >= 1 and out["failed"] == 0
     assert set(out["metrics"]) == {"train_tokens_per_s", "device_peak_gib",
@@ -43,6 +72,28 @@ def test_sound_run_is_correct(root):
     assert list(out)[-1] == "checks"
     assert out["compiles_in_window"] == 0
     assert all(c["value"] <= c["limit"] for c in out["checks"].values())
+
+
+def test_every_training_metric_reads_in_a_cell_added_by_files(root, sound):
+    """A cell added with files and entries alone reports every per-layer
+    metric: the program's counters from the untraced run's layer, the
+    device's from a trace summary, as a traced run attaches one."""
+    out, res = sound
+    bench = json.loads((root / "BENCHMARK.json").read_text())
+    assert all("workloads" not in m for m in bench["per_layer"])
+    loaded = harness.load_cell(CELL, root)
+    assert loaded.per_layer == bench["per_layer"]
+    layer = res.layer
+    assert len(layer.stats) == out["attempted"]
+    layer.trace = tracing.summarize(tracing.Events(
+        {"/device:TPU:0": [("fusion", 10 * MS, 20 * MS,
+                            "jit(scan)/while/body/attn/dot_general")]},
+        [(tracing.OPEN, 0.0, 1.0), (tracing.CLOSE, 100 * MS, 1.0)]))
+    got = harness.per_layer_metrics(loaded, layer)
+    assert set(got) == {m["name"] for m in bench["per_layer"]}
+    assert all(math.isfinite(v["value"]) for v in got.values())
+    assert set(SHARES) <= set(got)
+    assert 0 < sum(got[n]["value"] for n in SHARES) <= 100.5
 
 
 def _unchanged_state(params, grads, state, **kw):

@@ -1,7 +1,7 @@
 """The trace reduction: busy time, idle gaps named by the host, device time
-per operation, on a hand-made trace with a known answer and on a small
-trace recorded on a TPU v5e (tests/trace_v5e.json.gz, made by
-record_trace.py)."""
+per operation and per scope of the name stack, on a hand-made trace with a
+known answer and on a small trace recorded on a TPU v5e
+(tests/trace_v5e.json.gz, made by record_trace.py)."""
 from __future__ import annotations
 
 from pathlib import Path
@@ -15,13 +15,17 @@ MS = 1e6
 
 
 def _events():
-    # window [0, 100] ms; device ops 10-30 and 25-40 (overlapping) and
-    # 70-80; the host was in "fetch" from 40 to 70 and in "step" all along
+    # window [0, 100] ms; device ops 10-30 and 25-40 (overlapping, both in
+    # one scanned step) and 70-80; the host was in "fetch" from 40 to 70
+    # and in "step" all along
     return tracing.Events(
-        {"/device:TPU:0": [("fusion", 10 * MS, 20 * MS),
-                           ("copy", 25 * MS, 15 * MS),
-                           ("fusion", 70 * MS, 10 * MS),
-                           ("late", 120 * MS, 5 * MS)]},
+        {"/device:TPU:0": [
+            ("fusion", 10 * MS, 20 * MS,
+             "jit(step)/while/body/attn/dot_general"),
+            ("copy", 25 * MS, 15 * MS,
+             "jit(step)/while/body/transpose(jvp(mlp))/copy"),
+            ("fusion", 70 * MS, 10 * MS, "jit(adam)/mul"),
+            ("late", 120 * MS, 5 * MS, "jit(step)/attn/late")]},
         [(tracing.OPEN, 0.0, 1.0), (tracing.CLOSE, 100 * MS, 1.0),
          ("step", 0.0, 100 * MS), ("fetch", 40 * MS, 30 * MS)])
 
@@ -36,6 +40,21 @@ def test_known_answer():
                                         ["step", pytest.approx(0.02)],
                                         ["step", pytest.approx(0.01)]]
     assert s.breakdown["device_ops"][0] == ["fusion", pytest.approx(0.03)]
+    # the overlap of the two scanned ops counts once for their scopes
+    assert s.scope_seconds == pytest.approx(
+        {"step": 0.03, "while": 0.03, "body": 0.03, "attn": 0.02,
+         "mlp": 0.015, "adam": 0.01})
+
+
+@pytest.mark.parametrize("stack, want", [
+    ("jit(scan)/while/body/transpose(jvp(attn))/dot_general",
+     ["scan", "while", "body", "attn"]),
+    ("jvp(moe)/moe/mul", ["moe"]),
+    ("dot_general", []),
+    ("", []),
+])
+def test_scopes_of_a_name_stack(stack, want):
+    assert tracing.scopes(stack) == want
 
 
 def test_round_trip_and_no_marks(tmp_path):

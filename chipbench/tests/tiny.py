@@ -31,8 +31,8 @@ LIMITS = {"loss_gap": 0.001, "grad_gap": 0.005, "change_gap": 0.003}
 
 def make_root(tmp: Path, limits=None) -> Path:
     """A checkout-like tree: BENCHMARK.json naming two tiny training cells,
-    their configuration, traffic and limit files, and the real readers
-    and peaks."""
+    their configuration, traffic and limit files, and the real metric
+    entries, readers and peaks, as they stand."""
     root = Path(tmp)
     bench = root / "chipbench"
     for sub in ("configs", "traffic", "cells"):
@@ -55,9 +55,6 @@ def make_root(tmp: Path, limits=None) -> Path:
                           "why": "test"})
         (bench / "cells" / f"{cell}.json").write_text(
             json.dumps({"limits": limits or LIMITS}))
-    names = [w["name"] for w in workloads]
-    for m in real["per_layer"]:
-        m["workloads"] = names
     real.update(configs=configs, workloads=workloads)
     (root / "BENCHMARK.json").write_text(json.dumps(real))
     return root

@@ -7,6 +7,10 @@ The window is marked in the trace itself by two host annotations,
   device (the ``XLA Ops`` line of each ``/device:`` plane), averaged over
   the devices used; the idle share is 1 - busy / window;
 * device time per operation name, summed, for ``breakdown.device_ops``;
+* device time per scope of the JAX name stack each operation was traced
+  under (``jax.named_scope``): the union of the intervals of the
+  operations under that scope, so that a ``while`` and the operations
+  inside it count once;
 * every idle gap between device operations, named by what the host was
   doing in it: the shortest host event that covers at least half of the
   gap, else the one that overlaps it most, else ``"unattributed"``.
@@ -26,8 +30,12 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 OPEN, CLOSE = "chipbench.window_open", "chipbench.window_close"
 TOP = 10
+# the stats of a device operation that may carry its JAX name stack, in
+# the order they are tried
+NAME_STACK_STATS = ("tf_op", "op_name")
 
-# (name, start_ns, duration_ns)
+# host events: (name, start_ns, duration_ns); device operations add the
+# name stack: (name, start_ns, duration_ns, name_stack)
 Event = Tuple[str, float, float]
 
 
@@ -53,8 +61,10 @@ class Events:
 class Summary:
     busy_s: float
     window_s: float
-    # per operation name, device seconds in the window (summed over devices)
+    # per operation name and per scope of the name stack, device seconds
+    # in the window (summed over devices)
     op_seconds: Dict[str, float]
+    scope_seconds: Dict[str, float]
     breakdown: dict
 
     @property
@@ -85,8 +95,31 @@ def op_name(hlo: str) -> str:
     return hlo.split(" = ", 1)[0].lstrip("%")
 
 
+def scopes(name_stack: str) -> List[str]:
+    """The scopes of a name stack, outermost first, without the operation
+    itself and with transformations unwrapped:
+    ``jit(scan)/while/body/transpose(jvp(attn))/dot_general`` gives
+    ``scan``, ``while``, ``body``, ``attn``."""
+    out = []
+    for part in name_stack.split("/")[:-1]:
+        while part.endswith(")") and "(" in part:
+            part = part[part.index("(") + 1:-1]
+        if part and part not in out:
+            out.append(part)
+    return out
+
+
+def _name_stack(stats) -> str:
+    found = dict(stats)
+    for key in NAME_STACK_STATS:
+        if found.get(key):
+            return str(found[key])
+    return ""
+
+
 def load_events(path: str) -> Events:
-    """Device operations and host events of an ``.xplane.pb``."""
+    """Device operations, each with its name stack, and host events of an
+    ``.xplane.pb``."""
     from jax.profiler import ProfileData
     pd = ProfileData.from_file(path)
     device_ops: Dict[str, List[Event]] = {}
@@ -98,7 +131,8 @@ def load_events(path: str) -> Events:
             if line is None:
                 continue
             device_ops[plane.name] = [
-                (op_name(e.name), float(e.start_ns), float(e.duration_ns))
+                (op_name(e.name), float(e.start_ns), float(e.duration_ns),
+                 _name_stack(e.stats))
                 for e in line.events]
         elif plane.name.startswith("/host:CPU"):
             for line in plane.lines:
@@ -158,22 +192,28 @@ def _name_gap(a: float, b: float, host: Sequence[Event]) -> str:
 
 def summarize(events: Events, devices: Optional[Sequence[str]] = None
               ) -> Summary:
-    """Reduce one traced window to busy time, per-operation device time
-    and the breakdown."""
+    """Reduce one traced window to busy time, device time per operation
+    and per scope, and the breakdown."""
     lo, hi = _window(events.host)
     planes = sorted(devices or events.device_ops)
     if not planes:
         raise ValueError("the trace has no device operations")
     busy = 0.0
     op_ns: Dict[str, float] = defaultdict(float)
+    scope_ns: Dict[str, float] = defaultdict(float)
     gaps: List[Tuple[float, float]] = []
     for plane in planes:
         spans = []
-        for name, s, d in events.device_ops.get(plane, []):
+        by_scope: Dict[str, list] = defaultdict(list)
+        for name, s, d, *stack in events.device_ops.get(plane, []):
             a, b = max(s, lo), min(s + d, hi)
             if b > a:
                 spans.append((a, b))
                 op_ns[name] += b - a
+                for scope in scopes(stack[0] if stack else ""):
+                    by_scope[scope].append((a, b))
+        for scope, intervals in by_scope.items():
+            scope_ns[scope] += sum(b - a for a, b in _union(intervals))
         merged = _union(spans)
         busy += sum(b - a for a, b in merged)
         edges = [lo] + [x for ab in merged for x in ab] + [hi]
@@ -187,5 +227,6 @@ def summarize(events: Events, devices: Optional[Sequence[str]] = None
     return Summary(
         busy_s=busy / len(planes) / 1e9, window_s=(hi - lo) / 1e9,
         op_seconds={k: v / 1e9 for k, v in op_ns.items()},
+        scope_seconds={k: v / 1e9 for k, v in scope_ns.items()},
         breakdown={"device_ops": [[k, v / 1e9] for k, v in ops],
                    "idle_gaps": idle})
